@@ -40,7 +40,8 @@ def run(quick: bool = True) -> list[dict]:
         lambda q, k, v: ref.flash_attention_ref(q, k, v, causal=True)
     )
     us = _time(jitted_ref, q, q, q)
-    got = flash_attention(q, q, q, causal=True, block_q=256, block_k=256)
+    got = flash_attention(q, q, q, causal=True, block_q=256, block_k=256,
+                          interpret=True)
     err = float(jnp.max(jnp.abs(
         got.astype(jnp.float32)
         - jitted_ref(q, q, q).astype(jnp.float32))))
@@ -54,7 +55,8 @@ def run(quick: bool = True) -> list[dict]:
     kv_len = jnp.full((8,), sd, jnp.int32)
     jit_dec = jax.jit(ref.decode_attention_ref)
     us = _time(jit_dec, qd, kc, kc, kv_len)
-    got = decode_attention(qd, kc, kc, kv_len, block_k=256)
+    got = decode_attention(qd, kc, kc, kv_len, block_k=256,
+                           interpret=True)
     err = float(jnp.max(jnp.abs(
         got.astype(jnp.float32)
         - jit_dec(qd, kc, kc, kv_len).astype(jnp.float32))))
@@ -70,7 +72,7 @@ def run(quick: bool = True) -> list[dict]:
     cm = jax.random.normal(ks[7], (2, ss, 128))
     jit_ssd = jax.jit(ref.ssd_ref)
     us = _time(jit_ssd, x, dt, a, bm, cm)
-    y1, s1 = ssd(x, dt, a, bm, cm, chunk=256)
+    y1, s1 = ssd(x, dt, a, bm, cm, chunk=256, interpret=True)
     y2, s2 = jit_ssd(x, dt, a, bm, cm)
     err = float(jnp.max(jnp.abs(y1 - y2)))
     rows.append(row(f"kernels/ssd/S{ss}", us,

@@ -24,25 +24,11 @@ import jax.numpy as jnp
 
 
 def _shard_map(f, mesh, in_specs, out_specs, axis_names):
-    """Partial-manual shard_map across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map`` (kwargs ``axis_names`` /
-    ``check_vma``); older releases only have
-    ``jax.experimental.shard_map.shard_map`` (``auto`` / ``check_rep``).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=axis_names, check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    try:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   auto=auto, check_rep=False)
-    except TypeError:  # very old: no `auto` (fully-manual only)
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    """Partial-manual ``jax.shard_map``: manual over ``axis_names``."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        axis_names=axis_names, check_vma=False,
+    )
 
 
 def quantize(g: jax.Array) -> tuple[jax.Array, jax.Array]:

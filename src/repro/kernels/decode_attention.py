@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 NEG_INF = -1e30
 
 
@@ -67,7 +65,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     jax.jit, static_argnames=("block_k", "interpret")
 )
 def decode_attention(q, k_cache, v_cache, kv_len, *, block_k: int = 256,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool = False) -> jax.Array:
     """q: (B, H, D); caches: (B, H, S, D); kv_len: (B,) -> (B, H, D)."""
     b, h, s, d = k_cache.shape
     assert s % block_k == 0, (s, block_k)
@@ -103,7 +101,7 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, block_k: int = 256,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -165,7 +163,7 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """q: (B, Hq, D); k/v_pages: (NP, Hkv, ps, D) with Hq % Hkv == 0
     (GQA: query head hi reads kv head hi // g through the index map —
     the shared pool is never replicated); page_table: (B, MP) int32
@@ -213,7 +211,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,8 +1,21 @@
-"""Dispatching wrappers: Pallas kernel on TPU (or interpret mode for
-validation), pure-jnp oracle otherwise.
+"""The one kernel decision: compiled Pallas kernels on TPU, jnp elsewhere.
 
-``set_backend("pallas")`` routes the model hot-spots through the
-kernels; the default "jnp" keeps CPU dry-runs and tests on the oracle.
+Two kernels sit on the served path, and both run compiled exactly when
+JAX's default backend is a TPU:
+
+- ``paged_decode_attention`` — the paged plane's one-token decode
+  (``Model._attn_block``: every K=1 decode and every ``decode_block``
+  iteration);
+- ``page_gather`` — linearizing a request's pages for the P/D hand-off
+  (``kv_manager.gather_slot_kv``).
+
+The slot plane's contiguous ``decode_attention`` and ``flash_attention``
+kernels need shapes padded prefill breaks (``S % block == 0``, no right
+padding), so the slot plane always runs jnp and those kernels, ``ssd``
+and ``rmsnorm`` stay off the served path: only the kernel tests (in
+interpret mode) and ``benchmarks/bench_kernels.py`` call them.  No
+kernel interprets unless its caller passes ``interpret=True``; called
+on another backend without it, Pallas raises.
 """
 
 from __future__ import annotations
@@ -10,72 +23,19 @@ from __future__ import annotations
 import jax
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import decode_attention as _pl_decode
-from repro.kernels.decode_attention import (
-    paged_decode_attention as _pl_paged_decode,
-)
-from repro.kernels.flash_attention import flash_attention as _pl_flash
+from repro.kernels.decode_attention import paged_decode_attention
 from repro.kernels.page_gather import page_gather as _pl_page_gather
-from repro.kernels.rmsnorm import rmsnorm as _pl_rmsnorm
-from repro.kernels.ssd import ssd as _pl_ssd
-
-_BACKEND = "jnp"
 
 
-def set_backend(name: str) -> None:
-    global _BACKEND
-    assert name in ("jnp", "pallas", "pallas_interpret")
-    _BACKEND = name
+def kernels_enabled() -> bool:
+    """True when the served path runs the compiled Pallas kernels."""
+    return jax.default_backend() == "tpu"
 
 
-def get_backend() -> str:
-    return _BACKEND
+def page_gather(pages, page_ids):
+    if kernels_enabled():
+        return _pl_page_gather(pages, page_ids)
+    return ref.page_gather_ref(pages, page_ids)
 
 
-def _interpret() -> bool:
-    if _BACKEND == "pallas_interpret":
-        return True
-    return jax.default_backend() != "tpu"
-
-
-def flash_attention(q, k, v, *, causal=True, window=0, **kw):
-    if _BACKEND == "jnp":
-        return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
-    return _pl_flash(
-        q, k, v, causal=causal, window=window, interpret=_interpret(), **kw
-    )
-
-
-def decode_attention(q, k_cache, v_cache, kv_len, **kw):
-    if _BACKEND == "jnp":
-        return ref.decode_attention_ref(q, k_cache, v_cache, kv_len)
-    return _pl_decode(q, k_cache, v_cache, kv_len, interpret=_interpret(),
-                      **kw)
-
-
-def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, **kw):
-    if _BACKEND == "jnp":
-        return ref.paged_decode_attention_ref(
-            q, k_pages, v_pages, page_table, kv_len
-        )
-    return _pl_paged_decode(q, k_pages, v_pages, page_table, kv_len,
-                            interpret=_interpret(), **kw)
-
-
-def page_gather(pages, page_ids, **kw):
-    if _BACKEND == "jnp":
-        return ref.page_gather_ref(pages, page_ids)
-    return _pl_page_gather(pages, page_ids, interpret=_interpret(), **kw)
-
-
-def ssd(x, dt, a, b_mat, c_mat, *, chunk=256, **kw):
-    if _BACKEND == "jnp":
-        return ref.ssd_ref(x, dt, a, b_mat, c_mat)
-    return _pl_ssd(x, dt, a, b_mat, c_mat, chunk=chunk,
-                   interpret=_interpret(), **kw)
-
-
-def rmsnorm(x, scale, *, eps=1e-5, **kw):
-    if _BACKEND == "jnp":
-        return ref.rmsnorm_ref(x, scale, eps=eps)
-    return _pl_rmsnorm(x, scale, eps=eps, interpret=_interpret(), **kw)
+__all__ = ["kernels_enabled", "page_gather", "paged_decode_attention"]

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _gather_kernel(pt_ref, pages_ref, o_ref):
     # the index maps did all the work: copy one page tile through VMEM
@@ -32,7 +30,7 @@ def _gather_kernel(pt_ref, pages_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def page_gather(pages, page_ids, *, interpret: bool = True) -> jax.Array:
+def page_gather(pages, page_ids, *, interpret: bool = False) -> jax.Array:
     """pages: (NP, H, ps, D); page_ids: (M,) int32 (-1 = unallocated,
     clamped — callers slice the output to the valid token count).
     Returns the sequence's cache linearized to (H, M*ps, D)."""
@@ -56,7 +54,7 @@ def page_gather(pages, page_ids, *, interpret: bool = True) -> jax.Array:
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((h, m, ps, d), pages.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -30,7 +28,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
     jax.jit, static_argnames=("eps", "block_rows", "interpret")
 )
 def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int = 256,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool = False) -> jax.Array:
     """x: (..., D); scale: (D,)."""
     orig_shape = x.shape
     d = x.shape[-1]
@@ -50,7 +48,7 @@ def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int = 256,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

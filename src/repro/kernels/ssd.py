@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
                 state_ref, *, q: int, n_chunks: int):
@@ -84,7 +82,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd(x, dt, a, b_mat, c_mat, *, chunk: int = 256,
-        interpret: bool = True):
+        interpret: bool = False):
     """Chunked SSD, single B/C group.
 
     x: (B, S, H, P); dt: (B, S, H); a: (H,) negative;
@@ -128,7 +126,7 @@ def ssd(x, dt, a, b_mat, c_mat, *, chunk: int = 256,
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

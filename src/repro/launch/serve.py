@@ -44,6 +44,7 @@ from repro.core.faults import FaultInjector
 from repro.core.request import FOUR_TASK_SET, TASKS, TWO_TASK_SET
 from repro.core.scaler import ScalerConfig
 from repro.core.slo_mapper import PrioritySLOMapper, bands_from_tasks
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.cluster import Cluster, ClusterConfig
 from repro.serving.workload import poisson_workload, shared_prefix_workload
 
@@ -127,6 +128,7 @@ def run_online(args, cfg: ClusterConfig) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="qwen7b")
     ap.add_argument("--smoke", action="store_true",

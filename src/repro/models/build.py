@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
+from repro.kernels import ops
 from repro.models import attention as attn
 from repro.models import mamba2, moe
 from repro.models.common import (
@@ -152,10 +153,6 @@ class Model:
     kv_repeat: int = 1
     remat: bool = False
     q_chunk: int = 512
-    # Route attention/SSD hot-spots through the Pallas kernels
-    # (interpret-mode on CPU).  Requires kernel-aligned shapes:
-    # S % block == 0 and no right-padding (full lens).
-    use_kernels: bool = False
     # Pad the embedding/vocab dim (Megatron-style) so it shards over the
     # model axis; labels never index the pad ids.
     vocab_pad: int = 0
@@ -259,9 +256,9 @@ class Model:
                 positions, valid,
             )
             kv_len = positions[:, 0] + lens
-            if self.use_kernels and s == 1:
-                from repro.kernels import ops
-                # GQA handled inside the kernel's index map — the page
+            if s == 1 and ops.kernels_enabled():
+                # the one kernel decision (see repro.kernels.ops); GQA
+                # is handled inside the kernel's index map — the page
                 # pool stays at Hkv heads, never replicated
                 ctx = ops.paged_decode_attention(
                     q[:, :, 0, :], kp, vp, page_table, kv_len,
@@ -277,34 +274,18 @@ class Model:
                 cache["k"], cache["v"], cache["pos"], k, v, positions[:, 0],
                 window=window,
             )
-            if self.use_kernels and window == 0:
-                from repro.kernels import ops
-                g = q.shape[1] // kc.shape[1]
-                ctx = ops.decode_attention(
-                    q[:, :, 0, :],
-                    jnp.repeat(kc, g, axis=1),
-                    jnp.repeat(vc, g, axis=1),
-                    lens,
-                )[:, :, None, :]
-            else:
-                ctx = attn.decode_attention(
-                    q, kc, vc, q_pos=positions[:, 0], kv_pos=kv_pos,
-                    kv_len=lens, causal=causal, window=window,
-                )
+            # slot plane: jnp only — its kernels' alignment needs are
+            # broken by padded prefill (see repro.kernels.ops)
+            ctx = attn.decode_attention(
+                q, kc, vc, q_pos=positions[:, 0], kv_pos=kv_pos,
+                kv_len=lens, causal=causal, window=window,
+            )
             new_cache = {"k": kc, "v": vc, "pos": kv_pos}
         else:
-            if self.use_kernels:
-                from repro.kernels import ops
-                g = q.shape[1] // k.shape[1]
-                ctx = ops.flash_attention(
-                    q, jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1),
-                    causal=causal, window=window,
-                )
-            else:
-                ctx = attn.chunked_attention(
-                    q, k, v, lens=lens, causal=causal, window=window,
-                    q_chunk=self.q_chunk, unroll=self.unroll,
-                )
+            ctx = attn.chunked_attention(
+                q, k, v, lens=lens, causal=causal, window=window,
+                q_chunk=self.q_chunk, unroll=self.unroll,
+            )
             if make_cache:
                 new_cache = self._build_cache(k, v, lens, window, cache_len)
         b, s = x.shape[:2]
@@ -350,8 +331,7 @@ class Model:
         ssm_state = cache["ssm"] if cache is not None else None
         y, (new_conv, new_ssm) = mamba2.mamba_block(
             bp["mamba"], h, cfg, conv_state=conv_state, ssm_state=ssm_state,
-            decode=decode, use_kernels=self.use_kernels,
-            unroll=self.unroll, lens=lens if make_cache else None,
+            decode=decode, unroll=self.unroll, lens=lens if make_cache else None,
         )
         new_cache = None
         if make_cache or decode:

@@ -213,7 +213,7 @@ def ssd_decode_step(state, x_t, dt_t, a, b_t, c_t):
 
 def mamba_block(params: dict, x: jax.Array, cfg: ModelConfig, *,
                 conv_state=None, ssm_state=None, decode: bool = False,
-                use_kernels: bool = False, unroll: bool = False,
+                unroll: bool = False,
                 lens=None):
     """x: (B, S, d) -> (y: (B, S, d), (conv_state, ssm_state)).
 
@@ -259,14 +259,6 @@ def mamba_block(params: dict, x: jax.Array, cfg: ModelConfig, *,
             ssm_state, xh[:, 0], dt[:, 0], a, b_mat[:, 0], c_mat[:, 0]
         )
         y = y_t[:, None]
-    elif use_kernels and g == 1 and ssm_state is None and (
-        s % cfg.ssm.chunk_size == 0
-    ):
-        from repro.kernels import ops
-        y, new_ssm = ops.ssd(
-            xh, dt, a, b_mat[:, :, 0, :], c_mat[:, :, 0, :],
-            chunk=cfg.ssm.chunk_size,
-        )
     else:
         y, new_ssm = ssd_scan(
             xh, dt, a, b_mat, c_mat, chunk=cfg.ssm.chunk_size,

@@ -313,24 +313,26 @@ class Cluster:
                 max_spec_len=self.cfg.max_spec_len,
             )
         self._engine_model = build_model(self.cfg.model)
-        self._engine_params = self._engine_model.init(
-            jax.random.key(self.cfg.seed)
-        )
-        # per-replica weight ownership: the seed tree is provisioning
-        # SOURCE material only (host offload + disk checkpoint + the
-        # warmup engine below) — every replica gets its OWN tree via a
-        # real Table-2 transport, and scale-out measures the move
-        self.weights = WeightManager(self._engine_params, tl=self.tl)
+        seed = self._engine_model.init(jax.random.key(self.cfg.seed))
+        # per-replica weight ownership, one device copy per replica at
+        # every moment: the WeightManager keeps the seed's host offload
+        # and disk checkpoint as provisioning SOURCE material, replica 0
+        # adopts the device tree itself, and every other replica gets
+        # its OWN tree via a real Table-2 transport (scale-out measures
+        # the move).  The cluster keeps no device reference of its own.
+        self.weights = WeightManager(seed, tl=self.tl)
+        self.weights.adopt(0, seed)
         self._fn_cache: dict = {}   # share jitted steps across replicas
         self.truth = None
         self._kv_cap = 0
         self.fitted = FittedLatencyModel()
         # warm the jitted step functions into the shared fn_cache with a
-        # throwaway engine and a DETACHED profiler: XLA compile time
-        # must pollute neither the run's virtual clock (every queued
-        # request's TTFT) nor the Eq. 5 fit the Dispatcher budgets with
+        # throwaway engine over replica 0's tree and a DETACHED
+        # profiler: XLA compile time must pollute neither the run's
+        # virtual clock (every queued request's TTFT) nor the Eq. 5 fit
+        # the Dispatcher budgets with
         warm = InferenceEngine(
-            self._engine_model, self._engine_params, self._engine_cfg,
+            self._engine_model, seed, self._engine_cfg,
             profiler=FittedLatencyModel(), fn_cache=self._fn_cache,
         )
         n_warm = max(1, min(4, self._engine_cfg.max_len - 2))
@@ -370,7 +372,7 @@ class Cluster:
             for b in range(1, ecfg.prefill_batch + 1):
                 for pad in pads:
                     fn = warm._prefill_fn(pad)
-                    out, _ = fn(self._engine_params,
+                    out, _ = fn(seed,
                                 jnp.zeros((b, pad), jnp.int32),
                                 jnp.ones((b,), jnp.int32))
                     jax.block_until_ready(out)
@@ -392,6 +394,9 @@ class Cluster:
                      "cpu": ("cpu", "disk")}.get(strategy, (strategy,))
             params = None
             last_err: Optional[Exception] = None
+            if self.weights.owns(wid):
+                # replica 0 adopted the seed tree at init: no transfer
+                params, chain = self.weights.params_of(wid), ()
             for i, s in enumerate(chain):
                 if (self.faults is not None and i + 1 < len(chain)
                         and self.faults.fail_weight_load(self.now, s)):

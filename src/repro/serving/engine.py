@@ -109,6 +109,13 @@ class EngineConfig:
         return cls(**kw)
 
 
+def home_device(params):
+    """The single device holding ``params``, or None when they span
+    several (a mesh-sharded replica keeps JAX's own placement)."""
+    devs = {d for leaf in jax.tree.leaves(params) for d in leaf.devices()}
+    return devs.pop() if len(devs) == 1 else None
+
+
 class InferenceEngine:
     def __init__(self, model: Model, params, cfg: EngineConfig,
                  profiler: Optional[FittedLatencyModel] = None,
@@ -129,12 +136,18 @@ class InferenceEngine:
         cache = fn_cache if fn_cache is not None else {}
         self.slots = SlotManager(cfg.n_slots)
         self.prefix = None  # PrefixCache, attached on the paged plane
+        # the replica's home device: its KV pool and page table live
+        # beside its weights, so a replica on device i never computes
+        # on (or silently migrates to) the default device
+        self.device = home_device(params)
         if self.paged:
             self.kv = PagedKVManager(
-                cfg.n_slots, cfg.max_len, cfg.page_size, cfg.n_pages
+                cfg.n_slots, cfg.max_len, cfg.page_size, cfg.n_pages,
+                device=self.device,
             )
-            self.caches = model.init_paged_cache(
-                cfg.n_slots, cfg.max_len, cfg.page_size, self.kv.n_pages
+            self.caches = self._on_device(
+                model.init_paged_cache, cfg.n_slots, cfg.max_len,
+                cfg.page_size, self.kv.n_pages,
             )
             self.axes = model.paged_cache_axes()
             if "chunk" not in cache:
@@ -163,7 +176,9 @@ class InferenceEngine:
                     "slot fallback"
                 )
             self.kv = None
-            self.caches = model.init_cache(cfg.n_slots, cfg.max_len)
+            self.caches = self._on_device(
+                model.init_cache, cfg.n_slots, cfg.max_len
+            )
             self.axes = model.cache_axes()
             if "decode" not in cache:
                 cache["decode"] = jax.jit(model.decode_step)
@@ -248,6 +263,14 @@ class InferenceEngine:
                 max_ngram=self._spec_cfg.max_ngram,
                 min_ngram=self._spec_cfg.min_ngram,
             )
+
+    def _on_device(self, make, *args):
+        """Build a fresh cache tree on the home device (created there,
+        so the pool never passes through the default device)."""
+        if self.device is None:
+            return make(*args)
+        with jax.default_device(self.device):
+            return jax.device_put(make(*args), self.device)
 
     def peek_prefix(self, prompt) -> int:
         """Hit length (tokens) a prefix-cache lookup would return for
@@ -592,9 +615,12 @@ class InferenceEngine:
         if not self.kv.ensure(s, payload.n_tokens):
             self.slots.free(s)
             return False
+        # the D2D hop: the payload moves onto this replica's device
+        # (a no-op when source and destination share one)
         self.caches = scatter_slot_kv(
             self.caches, self.axes, s,
-            np.asarray(self.kv.pages_of(s), np.int32), payload.kv,
+            np.asarray(self.kv.pages_of(s), np.int32),
+            jax.device_put(payload.kv, self.device),
         )
         if req.generated is None:
             req.generated = []
@@ -692,8 +718,9 @@ class InferenceEngine:
         mutation in between (prefill completion, retire, import,
         preemption) marks them dirty and forces one re-upload."""
         if self._dev_state is None or self._host_state_dirty:
-            self._dev_state = (jnp.asarray(self.last_token),
-                               jnp.asarray(self.pos))
+            self._dev_state = jax.device_put(
+                (self.last_token, self.pos), self.device
+            )
             self._host_state_dirty = False
         return self._dev_state
 

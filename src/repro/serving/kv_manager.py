@@ -115,8 +115,11 @@ class PagedKVManager:
     """
 
     def __init__(self, n_slots: int, max_len: int, page_size: int,
-                 n_pages: Optional[int] = None):
+                 n_pages: Optional[int] = None, device=None):
         self.page_size = page_size
+        # the engine's home device: the uploaded table lands beside the
+        # page pool it indexes (None = JAX's default placement)
+        self.device = device
         self.max_pages = -(-max_len // page_size)
         self.n_slots = n_slots
         if n_pages is None:
@@ -208,7 +211,7 @@ class PagedKVManager:
         decode steps hand the SAME buffer to the jitted step — no
         per-token host->device table upload."""
         if self._table_dev is None or self.dirty:
-            self._table_dev = jnp.asarray(self.table)
+            self._table_dev = jax.device_put(self.table, self.device)
             self.dirty = False
         return self._table_dev
 

@@ -241,8 +241,10 @@ class StreamingStats:
             "n_tokens": self.n_tokens,
             "mean_ttfb": round(float(np.mean(self._ttfb))
                                if self._ttfb else 0.0, 5),
+            "p50_ttfb": round(self._pct(self._ttfb, 50), 5),
             "p99_ttfb": round(self._pct(self._ttfb, 99), 5),
             "mean_itl": round(float(np.mean(self._itl))
                               if self._itl else 0.0, 6),
+            "p50_itl": round(self._pct(self._itl, 50), 6),
             "p99_itl": round(self._pct(self._itl, 99), 6),
         }

@@ -55,13 +55,28 @@ from repro.distributed.sharding import current_rules
 STRATEGIES = ("d2d", "cpu", "disk")
 
 
+def _owned_put(h: np.ndarray, sharding) -> jax.Array:
+    """Host -> device copy the replica owns.  On an accelerator the put
+    already yields a fresh device buffer; a CPU-device put of a host
+    array may be zero-copy, so there (only) an explicit copy keeps the
+    replica from sharing the offload's memory — and keeps an
+    accelerator from holding each leaf twice."""
+    x = jax.device_put(h, sharding)
+    if all(d.platform == "cpu" for d in x.devices()):
+        x = jnp.copy(x)
+    return x
+
+
 class WeightManager:
     """Owns the per-replica params trees of one served model.
 
-    ``seed_params`` is retained only as provisioning *source* material
-    (host offload + disk checkpoint) — replicas never alias it; every
-    ``provision``/``adopt`` hands a replica its own tree, and
-    ``release`` drops it (scale-in reclaims the copy's memory).
+    Only ``seed_params``' host offload and disk checkpoint are kept, as
+    provisioning *source* material — never the device tree itself, so
+    each replica holds the one device copy of its weights: ``adopt``
+    registers a tree the caller hands over (the cluster's replica 0
+    takes the seed tree this way), every ``provision`` materializes a
+    fresh tree, and ``release`` drops it (scale-in reclaims the copy's
+    memory).
     """
 
     def __init__(self, seed_params: Any, tl=None,
@@ -82,7 +97,7 @@ class WeightManager:
             self._tmp = tempfile.TemporaryDirectory(prefix="hfx-weights-")
             ckpt_dir = self._tmp.name
         self.ckpt_dir = ckpt_dir
-        save_checkpoint(self.ckpt_dir, 0, seed_params)
+        save_checkpoint(self.ckpt_dir, 0, self.host)
         assert checkpoint_nbytes(self.ckpt_dir, 0) == self.nbytes
 
     # -- ownership registry ----------------------------------------------------
@@ -97,8 +112,8 @@ class WeightManager:
         return sorted(self._owned)
 
     def adopt(self, wid: int, params: Any) -> None:
-        """Register an externally materialized tree (e.g. the seed
-        replica constructed before this manager existed)."""
+        """Register an externally materialized tree the caller hands
+        over (e.g. the seed tree, adopted by replica 0)."""
         if wid in self._owned:
             raise ValueError(f"replica {wid} already owns a params tree")
         self._owned[wid] = params
@@ -160,13 +175,7 @@ class WeightManager:
 
             params = jax.tree.map(pull, src)
         elif strategy == "cpu":
-            # the copy after device_put matters: a CPU-device put of a
-            # host array is zero-copy, and every "cpu" replica would
-            # otherwise share the offload's buffers instead of owning
-            # its own tree
-            params = jax.tree.map(
-                lambda h: jnp.copy(jax.device_put(h, sh)), self.host
-            )
+            params = jax.tree.map(lambda h: _owned_put(h, sh), self.host)
         else:  # disk
             shardings = (None if sh is None
                          else jax.tree.map(lambda _: sh, self.host))

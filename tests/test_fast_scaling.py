@@ -4,6 +4,7 @@ ownership via WeightManager, the three Table-2 provisioning transports
 TLManager model, and the Cluster's scale-out/scale-in commit paths."""
 
 import dataclasses
+import gc
 
 import jax
 import numpy as np
@@ -181,14 +182,52 @@ def _force_actions(c, actions, now=1.0):
 
 
 def test_engine_replicas_own_their_weights(stack):
-    """Tentpole ownership model: the initial replica's params tree is
-    its OWN (provisioned through a transport), not an alias of the
-    cluster's seed tree."""
-    c = _engine_cluster()
-    w0 = c.workers[0]
-    assert c.weights is not None and c.weights.owns(0)
-    assert w0.engine.params is not c._engine_params
-    assert _distinct_buffers(c._engine_params, w0.engine.params)
+    """Ownership model: replica 0 owns the seed tree outright (adopted,
+    so the cluster keeps no second device copy beside it), and every
+    further replica gets its OWN tree through a transport."""
+    c = Cluster(ClusterConfig(
+        model=SMOKE, n_workers=2, backend="engine",
+        engine=EngineConfig.smoke(),
+    ))
+    w0, w1 = c.workers
+    assert c.weights.owns(0) and c.weights.owns(1)
+    assert c.weights.params_of(0) is w0.engine.params
+    assert c.weights.params_of(1) is w1.engine.params
+    assert not hasattr(c, "_engine_params")
+    assert _distinct_buffers(w0.engine.params, w1.engine.params)
+
+
+def _live_device_bytes() -> int:
+    gc.collect()
+    return sum(a.nbytes for a in jax.live_arrays())
+
+
+def _tree_bytes(tree) -> int:
+    return sum(x.nbytes for x in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("n_workers", [1, 2])
+def test_cluster_holds_one_weight_copy_per_replica(stack, n_workers):
+    """The one-weight-copy invariant: after construction (warm-up
+    included) the device holds each replica's params and KV pool and
+    nothing else of size — no seed tree beside replica 0, no leftover
+    warm-up pool."""
+    before = _live_device_bytes()
+    c = Cluster(ClusterConfig(
+        model=SMOKE, n_workers=n_workers, backend="engine",
+        engine=EngineConfig.smoke(),
+    ))
+    grown = _live_device_bytes() - before
+    engines = [w.engine for w in c.workers]
+    params = _tree_bytes(engines[0].params)
+    owned = sum(_tree_bytes(e.params) + _tree_bytes(e.caches)
+                for e in engines)
+    assert owned == n_workers * (params + _tree_bytes(engines[0].caches))
+    # page tables and decode state are a few KiB; any stray weight copy
+    # or KV pool would add at least a whole tree
+    slack = grown - owned
+    assert 0 <= slack < min(params, _tree_bytes(engines[0].caches)), (
+        grown, owned)
 
 
 def test_engine_scale_out_d2d_and_scale_in_release(stack):

@@ -535,7 +535,8 @@ def test_load_latest_sweep_on_empty_dir(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_online_malformed_jsonl_survives():
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src",
+               JAX_ENABLE_COMPILATION_CACHE="false")
     lines = "\n".join([
         "this is not json",
         '{"task":"gsm8k","l_in":12,"l_out":3}',
@@ -560,7 +561,8 @@ def test_online_malformed_jsonl_survives():
 
 
 def test_serve_fault_schedule_cli_sim():
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src",
+               JAX_ENABLE_COMPILATION_CACHE="false")
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--model", "qwen7b",
          "--workers", "2", "--qps", "40", "--n-per-task", "8",

@@ -1,13 +1,18 @@
-"""Pallas kernel sweeps (interpret mode) vs the pure-jnp oracles."""
+"""Pallas kernel sweeps (interpret mode) vs the pure-jnp oracles, and
+the one kernel decision: compiled on TPU, never a silent interpreter."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels import ops, ref
+from repro.kernels.decode_attention import (
+    decode_attention,
+    paged_decode_attention,
+)
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.page_gather import page_gather
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ssd import ssd
 
@@ -35,7 +40,7 @@ def test_flash_attention_sweep(s, d, bq, bk, causal, window, dtype):
     k = jax.random.normal(k1, shape, dtype)
     v = jax.random.normal(k2, shape, dtype)
     got = flash_attention(q, k, v, causal=causal, window=window,
-                          block_q=bq, block_k=bk)
+                          block_q=bq, block_k=bk, interpret=True)
     want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -53,7 +58,7 @@ def test_decode_attention_sweep(s, d, bk, dtype):
     kc = jax.random.normal(k1, (b, h, s, d), dtype)
     vc = jax.random.normal(k2, (b, h, s, d), dtype)
     kv_len = jnp.array([s, s // 2, 7][:b])
-    got = decode_attention(q, kc, vc, kv_len, block_k=bk)
+    got = decode_attention(q, kc, vc, kv_len, block_k=bk, interpret=True)
     want = ref.decode_attention_ref(q, kc, vc, kv_len)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -74,7 +79,7 @@ def test_ssd_sweep(s, h, p, n, chunk):
     a = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.5)
     bm = jax.random.normal(ks[3], (b, s, n))
     cm = jax.random.normal(ks[4], (b, s, n))
-    y1, s1 = ssd(x, dt, a, bm, cm, chunk=chunk)
+    y1, s1 = ssd(x, dt, a, bm, cm, chunk=chunk, interpret=True)
     y2, s2 = ref.ssd_ref(x, dt, a, bm, cm)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                rtol=2e-3, atol=2e-3)
@@ -89,7 +94,7 @@ def test_rmsnorm_sweep(rows, d, br, dtype):
     k0, k1 = jax.random.split(jax.random.key(3))
     x = jax.random.normal(k0, (rows, d), dtype)
     sc = jax.random.normal(k1, (d,)) * 0.1
-    got = rmsnorm(x, sc, block_rows=br)
+    got = rmsnorm(x, sc, block_rows=br, interpret=True)
     want = ref.rmsnorm_ref(x, sc)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -100,5 +105,45 @@ def test_rmsnorm_sweep(rows, d, br, dtype):
 def test_flash_attention_long_context_memory_shape():
     """Blocked kernel output matches shapes on longer sequences."""
     q = jax.random.normal(jax.random.key(4), (1, 2, 1024, 64))
-    out = flash_attention(q, q, q, causal=True, block_q=256, block_k=256)
+    out = flash_attention(q, q, q, causal=True, block_q=256, block_k=256,
+                          interpret=True)
     assert out.shape == q.shape
+
+
+def _kernel_calls():
+    x4 = jnp.ones((1, 2, 128, 64))
+    q3 = jnp.ones((2, 2, 64))
+    pages = jnp.ones((4, 2, 16, 64))
+    table = jnp.zeros((2, 2), jnp.int32)
+    lens = jnp.array([5, 9])
+    return {
+        "flash_attention": lambda: flash_attention(x4, x4, x4),
+        "decode_attention": lambda: decode_attention(
+            q3, jnp.ones((2, 2, 256, 64)), jnp.ones((2, 2, 256, 64)), lens),
+        "paged_decode_attention": lambda: paged_decode_attention(
+            q3, pages, pages, table, lens),
+        "page_gather": lambda: page_gather(pages, jnp.arange(2)),
+        "ssd": lambda: ssd(jnp.ones((1, 64, 2, 16)), jnp.ones((1, 64, 2)),
+                           -jnp.ones((2,)), jnp.ones((1, 64, 32)),
+                           jnp.ones((1, 64, 32)), chunk=32),
+        "rmsnorm": lambda: rmsnorm(jnp.ones((64, 128)), jnp.zeros((128,))),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_kernel_calls()))
+def test_kernel_without_interpret_raises_off_tpu(name):
+    """No kernel entry point interprets by default: off a TPU, a call
+    without ``interpret=True`` fails instead of running slowly."""
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(ValueError, match="interpret"):
+        _kernel_calls()[name]()
+
+
+def test_kernel_decision_follows_the_platform():
+    """The served path's one switch: kernels only where the backend is
+    a TPU; page_gather then falls back to its jnp oracle."""
+    assert ops.kernels_enabled() == (jax.default_backend() == "tpu")
+    pages = jax.random.normal(jax.random.key(5), (6, 2, 8, 16))
+    ids = jnp.array([4, 1, -1], jnp.int32)
+    np.testing.assert_array_equal(np.asarray(ops.page_gather(pages, ids)),
+                                  np.asarray(ref.page_gather_ref(pages, ids)))
