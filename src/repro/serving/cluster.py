@@ -33,6 +33,7 @@ from typing import TYPE_CHECKING, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.core.latency_model import (
     AnalyticLatencyModel,
@@ -562,10 +563,13 @@ class Cluster:
             return None
         now, _, kind, payload = heapq.heappop(self._events)
         self.now = now
-        self._handle(kind, payload, now)
+        with tracing.span("hfx.cluster.event", kind=kind) as sp:
+            self._handle(kind, payload, now, sp)
         return kind
 
-    def _handle(self, kind: str, payload, now: float) -> None:
+    def _handle(self, kind: str, payload, now: float, sp=None) -> None:
+        """Handle one event; ``sp`` is its span, which a worker step
+        and its ``step_done`` mark with the engine step's id."""
         cfg = self.cfg
         by_wid = self._by_wid
 
@@ -588,7 +592,11 @@ class Cluster:
                 self._dispatch_at - 1e-12
             ):
                 self._dispatch_at = None
-            self.policy.dispatch_pass(now)
+            with tracing.span("hfx.dispatch.pass",
+                              queued=self.policy.pending) as dp:
+                placed = self.policy.dispatch_pass(now)
+                tracing.annotate(dp, placed=lambda: sum(
+                    len(reqs) for _, reqs in placed))
             nw = self.policy.next_wakeup()
             if self.policy.pending() and nw is not None:
                 self._schedule_dispatch(max(nw, now + 1e-6))
@@ -603,6 +611,7 @@ class Cluster:
             else:
                 out = w.run_step(now)
                 if out is not None:
+                    tracing.annotate(sp, step=lambda: out.info.get("step"))
                     if (self.faults is not None
                             and self.faults.has_stragglers()):
                         f = self.faults.slowdown(w.wid, now)
@@ -619,6 +628,7 @@ class Cluster:
 
         elif kind == "step_done":
             wid, out = payload
+            tracing.annotate(sp, step=lambda: out.info.get("step"))
             w = by_wid[wid]
             w.step_pending = False
             if w.crashed:
@@ -628,11 +638,13 @@ class Cluster:
             ev = w.finish_step(out, now)
             # stream tokens before completions so a FIRST_TOKEN always
             # precedes its own FINISHED in any subscriber's log
-            if self.on_token is not None:
-                for rid, tok, t in ev.tokens:
-                    self.on_token(rid, tok, t)
-            for r in ev.finished:
-                self._finish(r, now)
+            with tracing.span("hfx.cluster.stream",
+                              tokens=lambda: len(ev.tokens)):
+                if self.on_token is not None:
+                    for rid, tok, t in ev.tokens:
+                        self.on_token(rid, tok, t)
+                for r in ev.finished:
+                    self._finish(r, now)
             if out.kind == "prefill":
                 for r in ev.parked:
                     if self.migrator is not None:
@@ -756,7 +768,8 @@ class Cluster:
                 # but only when new samples landed since last tick
                 n = self.fitted.n_samples()
                 if n > self._fit_seen:
-                    self.fitted.fit(min_samples=4)
+                    with tracing.span("hfx.cluster.fit", samples=n):
+                        self.fitted.fit(min_samples=4)
                     self._fit_seen = n
             if self.coordinator is not None:
                 # live-migration planning rides the monitor cadence:

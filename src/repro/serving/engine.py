@@ -43,6 +43,7 @@ on TPU; the compute path is the same model code the dry-run lowers.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from functools import partial
 from typing import Callable, Optional, Sequence
@@ -51,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.latency_model import FittedLatencyModel
 from repro.core.request import Request, RequestState
 from repro.core.token_budget import ntoken_limit
@@ -65,6 +67,13 @@ from repro.serving.kv_manager import (
     scatter_slot_kv,
 )
 from repro.serving.spec_decode import NGramDrafter, SpecConfig, slo_spec_len
+
+# step ids, unique in the process: a trace's spans name a step alike
+_STEP_IDS = itertools.count(1)
+
+
+def _rids(reqs) -> Optional[str]:
+    return ";".join(str(r.rid) for r in reqs) or None  # a span argument
 
 
 @dataclasses.dataclass
@@ -344,9 +353,45 @@ class InferenceEngine:
 
     # -- one engine step ------------------------------------------------------
     def step(self) -> dict:
-        """Run one prefill (chunk) or decode step; returns event info."""
-        if self.paged:
-            return self._step_paged()
+        """Run one prefill (chunk) or decode step; returns event info
+        with the step's id.  Spans: ``hfx.engine.step`` (its ``kind``,
+        ``k``, ``tokens`` out) holds ``plan`` up to the dispatch, then
+        ``commit`` (``_dispatch``)."""
+        self._step_id = next(_STEP_IDS)
+        with tracing.span("hfx.engine.step",
+                          step=self._step_id) as self._span:
+            self._phase = tracing.span("hfx.engine.plan")
+            self._phase.__enter__()
+            try:
+                info = (self._step_paged() if self.paged
+                        else self._step_slots())
+            finally:
+                self._phase.__exit__(None, None, None)
+            tracing.annotate(self._span, kind=lambda: info["kind"],
+                             k=lambda: info.get("k", 1),
+                             tokens=lambda: info.get("tokens"))
+        info["step"] = self._step_id
+        return info
+
+    def _dispatch(self, call: Callable, **args):
+        """The step's jitted dispatch (``hfx.engine.launch``) and sync
+        on its first output (``hfx.engine.wait``); returns the outputs
+        and the measured step time.  ``args`` annotate the step."""
+        tracing.annotate(self._span, **args)
+        self._phase.__exit__(None, None, None)
+        with tracing.span("hfx.engine.launch"):
+            t0 = time.perf_counter()
+            out = call()
+        with tracing.span("hfx.engine.wait"):
+            jax.block_until_ready(out[0])
+            dt = time.perf_counter() - t0
+        self.clock += dt
+        self.n_dispatches += 1
+        self._phase = tracing.span("hfx.engine.commit")
+        self._phase.__enter__()
+        return out, dt
+
+    def _step_slots(self) -> dict:
         admitted = self._admit()
         if admitted:
             return self._prefill(admitted)
@@ -452,15 +497,16 @@ class InferenceEngine:
             start[s] = r.prefill_progress
             lens[s] = t
 
-        t0 = time.perf_counter()
-        logits, self.caches = self._chunk(
-            self.params, self.caches, self.kv.device_table(),
-            jnp.asarray(tokens), jnp.asarray(start), jnp.asarray(lens),
+        (logits, self.caches), dt = self._dispatch(
+            lambda: self._chunk(
+                self.params, self.caches, self.kv.device_table(),
+                jnp.asarray(tokens), jnp.asarray(start), jnp.asarray(lens),
+            ),
+            # requests whose first chunk runs now (queue wait ends here)
+            first_rids=lambda: _rids(
+                r for s, r in self.prefilling.items()
+                if takes[s] and r.prefill_progress == r.prefix_hit_tokens),
         )
-        logits = jax.block_until_ready(logits)
-        dt = time.perf_counter() - t0
-        self.clock += dt
-        self.n_dispatches += 1
         chunk_lens = [t for t in takes.values() if t > 0]
         self.profiler.observe_prefill(chunk_lens, dt)
         self.n_prefill_tokens += int(sum(chunk_lens))
@@ -847,31 +893,17 @@ class InferenceEngine:
         last_d, pos_d = self._device_state()
         eos = jnp.int32(-1 if cfg.eos_token is None else cfg.eos_token)
         fn = self._spec_block_fn(kpad)
-        t0 = time.perf_counter()
-        (toks, valid, last_f, pos_f), self.caches = fn(
-            self.params, self.caches, self.kv.device_table(),
-            last_d, pos_d, jnp.asarray(alive), jnp.asarray(rem),
-            eos, jnp.int32(cfg.max_len),
-            jnp.asarray(props), jnp.asarray(prop_lens),
-        )
-        toks, valid = jax.block_until_ready((toks, valid))
-        dt = time.perf_counter() - t0
-        self.clock += dt
-        self.n_dispatches += 1
+        (out, self.caches), dt = self._dispatch(
+            lambda: fn(
+                self.params, self.caches, self.kv.device_table(),
+                last_d, pos_d, jnp.asarray(alive), jnp.asarray(rem),
+                eos, jnp.int32(cfg.max_len),
+                jnp.asarray(props), jnp.asarray(prop_lens),
+            ))
         self.n_spec_dispatches += 1
         self.spec_depth_hist[kpad] = self.spec_depth_hist.get(kpad, 0) + 1
-        self._dev_state = (last_f, pos_f)
-        self._host_state_dirty = False
 
-        tk = np.asarray(toks)   # (n_slots, kpad+1)
-        vd = np.asarray(valid)  # (n_slots, kpad+1) bool
-        t_start = self.clock - dt
-        finish_at: dict[int, float] = {}
-        tok_ev: list[tuple] = []
-        n_emitted = 0
-        for s, r in self.active.items():
-            lanes = np.nonzero(vd[s])[0]
-            emitted = [int(tk[s][i]) for i in lanes]
+        def on_lane(s: int, r: Request, emitted: list) -> None:
             accepted = max(0, len(emitted) - 1)
             self.n_spec_proposed += int(prop_lens[s])
             self.n_spec_accepted += accepted
@@ -883,34 +915,13 @@ class InferenceEngine:
             st["sum_want"] += want_of[s]   # controller's chosen depth
             st["sum_k"] += int(prop_lens[s])
             st["accepted"] += accepted
-            if not emitted:
-                continue
-            r.generated.extend(emitted)
-            r.tokens_done = len(r.generated)
-            self.pos[s] += len(emitted)
-            self.last_token[s] = emitted[-1]
-            n_emitted += len(emitted)
-            for tok, lane in zip(emitted, lanes):
-                tok_ev.append(
-                    (r.rid, tok, t_start + dt * (lane + 1) / (kpad + 1))
-                )
-            last_lane = int(lanes[-1])
-            finish_at[s] = t_start + dt * (last_lane + 1) / (kpad + 1)
-            # rollback: rejected lanes' KV past the accepted position
-            # is dead weight — give whole pages back to the pool
-            self.kv.truncate(s, int(self.pos[s]))
-        # accepted-only Appendix-A attribution: trailing all-rejected
-        # lanes are trimmed by observe_decode_block, so rejected
-        # speculation never biases the Eq. 2 fit low
-        self.profiler.observe_decode_block(
-            [[pos0[s] + i for s in sorted(pos0) if vd[s, i]]
-             for i in range(kpad + 1)], dt,
-        )
-        self.n_decode_tokens += n_emitted
-        self._retire(finish_at)
-        return {"kind": "decode", "n": len(pos0), "k": kpad + 1,
-                "tokens": n_emitted, "time": dt, "spec": True,
-                "token_events": tok_ev}
+            if emitted:
+                # rollback: rejected lanes' KV past the accepted
+                # position is dead weight — give whole pages back
+                self.kv.truncate(s, int(self.pos[s]) + len(emitted))
+
+        return {**self._commit_block(out, kpad + 1, dt, pos0, on_lane),
+                "spec": True}
 
     def _decode_block_step(self, k: int) -> dict:
         """One fused K-iteration decode block (either plane): a single
@@ -932,20 +943,22 @@ class InferenceEngine:
         args = (self.params, self.caches)
         if self.paged:
             args += (self.kv.device_table(),)
-        t0 = time.perf_counter()
-        (toks, valid, last_f, pos_f), self.caches = fn(
-            *args, last_d, pos_d, jnp.asarray(alive), jnp.asarray(rem),
-            eos, jnp.int32(cfg.max_len),
-        )
-        toks, valid = jax.block_until_ready((toks, valid))
-        dt = time.perf_counter() - t0
-        self.clock += dt
-        self.n_dispatches += 1
+        (out, self.caches), dt = self._dispatch(
+            lambda: fn(*args, last_d, pos_d, jnp.asarray(alive),
+                       jnp.asarray(rem), eos, jnp.int32(cfg.max_len)))
         self.decode_block_hist[k] = self.decode_block_hist.get(k, 0) + 1
+        return self._commit_block(out, k, dt, pos0)
+
+    def _commit_block(self, out, k: int, dt: float, pos0: dict,
+                      on_lane: Optional[Callable] = None) -> dict:
+        """Commit a K-lane decode dispatch, a fused block or a
+        speculative verify (whose ``on_lane(slot, req, emitted)`` runs
+        before each slot's tokens land): keep its final state on the
+        device, append each slot's valid lanes, feed the fit, retire."""
+        toks, valid, last_f, pos_f = out
         # the scan's final state IS the next block's input — resident
         self._dev_state = (last_f, pos_f)
         self._host_state_dirty = False
-
         tk = np.asarray(toks)   # (n_slots, K)
         vd = np.asarray(valid)  # (n_slots, K) bool
         t_start = self.clock - dt
@@ -953,9 +966,10 @@ class InferenceEngine:
         tok_ev: list[tuple] = []  # (rid, token, t) stream events
         n_emitted = 0
         for s, r in self.active.items():
-            row = vd[s]
-            lanes = np.nonzero(row)[0]
+            lanes = np.nonzero(vd[s])[0]
             emitted = [int(tk[s][i]) for i in lanes]
+            if on_lane is not None:
+                on_lane(s, r, emitted)
             if not emitted:
                 continue
             r.generated.extend(emitted)
@@ -969,10 +983,11 @@ class InferenceEngine:
             # block's one sync already delivered the (n_slots, K) matrix
             for tok, lane in zip(emitted, lanes):
                 tok_ev.append((r.rid, tok, t_start + dt * (lane + 1) / k))
-            last_lane = int(lanes[-1])
-            finish_at[s] = t_start + dt * (last_lane + 1) / k
+            finish_at[s] = t_start + dt * (int(lanes[-1]) + 1) / k
         # Appendix-A attribution: K per-iteration samples of dt/K at
-        # the interpolated lengths (what per-token stepping observes)
+        # the interpolated lengths (what per-token stepping observes);
+        # a verify's trailing all-rejected lanes are trimmed there, so
+        # rejected speculation never biases the Eq. 2 fit low
         self.profiler.observe_decode_block(
             [[pos0[s] + i for s in sorted(pos0) if vd[s, i]]
              for i in range(k)], dt,
@@ -1012,20 +1027,11 @@ class InferenceEngine:
         lens = np.zeros((cfg.n_slots,), np.int32)
         for s in self.active:
             lens[s] = 1  # the new token lands at position pos[s]
-        t0 = time.perf_counter()
-        logits, self.caches = self._chunk(
+        return self._per_token_decode(lambda: self._chunk(
             self.params, self.caches, self.kv.device_table(),
             jnp.asarray(self.last_token[:, None]),
             jnp.asarray(self.pos), jnp.asarray(lens),
-        )
-        logits = jax.block_until_ready(logits)
-        dt = time.perf_counter() - t0
-        self.clock += dt
-        cur = [int(self.pos[s]) for s in sorted(self.active)]
-        self.profiler.observe_decode(cur, dt)
-
-        return self._finish_per_token_decode(
-            np.asarray(jnp.argmax(logits, axis=-1), np.int32), dt)
+        ))
 
     # ==========================================================================
     # Slot-based plane (monolithic prefill fallback)
@@ -1075,13 +1081,9 @@ class InferenceEngine:
             tokens[i, : len(r.prompt)] = r.prompt
             lens[i] = len(r.prompt)
         fn = self._prefill_fn(max_l)
-        t0 = time.perf_counter()
-        logits, cache = fn(self.params, jnp.asarray(tokens),
-                           jnp.asarray(lens))
-        logits = jax.block_until_ready(logits)
-        dt = time.perf_counter() - t0
-        self.clock += dt
-        self.n_dispatches += 1
+        (logits, cache), dt = self._dispatch(
+            lambda: fn(self.params, jnp.asarray(tokens), jnp.asarray(lens)),
+            first_rids=lambda: _rids(reqs))
         self.profiler.observe_prefill([len(r.prompt) for r in reqs], dt)
         self.n_prefill_tokens += int(sum(len(r.prompt) for r in reqs))
 
@@ -1115,25 +1117,20 @@ class InferenceEngine:
         k = self._decode_block_k()
         if k > 1:
             return self._decode_block_step(k)
-        t0 = time.perf_counter()
-        logits, self.caches = self._decode(
+        return self._per_token_decode(lambda: self._decode(
             self.params, self.caches, jnp.asarray(self.last_token),
-            jnp.asarray(self.pos),
-        )
-        logits = jax.block_until_ready(logits)
-        dt = time.perf_counter() - t0
-        self.clock += dt
-        cur = [int(self.pos[s]) for s in self.slots.active_slots()]
-        self.profiler.observe_decode(cur, dt)
+            jnp.asarray(self.pos)))
 
-        return self._finish_per_token_decode(
-            np.asarray(jnp.argmax(logits, axis=-1), np.int32), dt)
-
-    def _finish_per_token_decode(self, nxt, dt: float) -> dict:
-        """Shared K=1 tail for both planes: append the sampled token
-        per active slot, advance host state, account telemetry, and
-        retire — one place to keep the paged/slot paths in sync."""
+    def _per_token_decode(self, call: Callable) -> dict:
+        """Shared K=1 step for both planes: dispatch ``call`` (logits,
+        caches), append the sampled token per active slot, advance host
+        state, account telemetry, and retire — one place to keep the
+        paged/slot paths in sync."""
         n_tok = len(self.active)
+        (logits, self.caches), dt = self._dispatch(call)
+        self.profiler.observe_decode(
+            [int(self.pos[s]) for s in self.active], dt)
+        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         tok_ev: list[tuple] = []
         for s, r in list(self.active.items()):
             self.pos[s] += 1
@@ -1143,7 +1140,6 @@ class InferenceEngine:
             self.last_token[s] = tok
             tok_ev.append((r.rid, tok, self.clock))
         self._host_state_dirty = True
-        self.n_dispatches += 1
         self.decode_block_hist[1] = self.decode_block_hist.get(1, 0) + 1
         self.n_decode_tokens += n_tok
         self._retire()

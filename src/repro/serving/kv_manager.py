@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
+
 
 class SlotManager:
     """Batch-row allocator.  The free list is a min-heap, so ``alloc``
@@ -211,7 +213,9 @@ class PagedKVManager:
         decode steps hand the SAME buffer to the jitted step — no
         per-token host->device table upload."""
         if self._table_dev is None or self.dirty:
-            self._table_dev = jax.device_put(self.table, self.device)
+            with tracing.span("hfx.kv.table",
+                              pages=lambda: int(self._n_pages_of.sum())):
+                self._table_dev = jax.device_put(self.table, self.device)
             self.dirty = False
         return self._table_dev
 

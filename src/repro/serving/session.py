@@ -51,6 +51,7 @@ from typing import TYPE_CHECKING, Callable, Iterator, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core.request import Request, RequestState
 from repro.serving.metrics import COST_UNIT, RunMetrics, StreamingStats
 
@@ -285,7 +286,15 @@ class ServingSession:
     def submit_request(self, r: Request) -> ResponseHandle:
         """Submit a pre-built :class:`Request` (the workload-replay and
         batch-adapter path).  Performs arrival stamping, engine-plane
-        prompt materialization/validation, and the admission verdict."""
+        prompt materialization/validation, and the admission verdict.
+        Traced as ``hfx.session.submit`` (``rid``, ``admitted``)."""
+        with tracing.span("hfx.session.submit") as sp:
+            handle = self._submit(r)
+            tracing.annotate(sp, rid=r.rid, admitted=lambda: int(
+                r.state is not RequestState.REJECTED))
+        return handle
+
+    def _submit(self, r: Request) -> ResponseHandle:
         if self._closed:
             raise RuntimeError("session is closed")
         cl = self.cluster
