@@ -11,6 +11,7 @@ scalar-prefetch operand (SMEM) so masked tail blocks are skipped.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -113,107 +114,189 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, block_k: int = 256,
 # Paged decode attention (block/page KV layout)
 # ---------------------------------------------------------------------------
 #
-# The KV cache lives in a pool of fixed-size pages (n_pages, H, ps, D);
-# request b's logical token t sits at page_table[b, t // ps], offset
-# t % ps.  The page table is a scalar-prefetch operand: the *index map*
-# reads it to pick which physical page each grid step streams through
-# VMEM, so the kernel never materializes a gathered contiguous cache.
+# The KV cache lives in a pool of fixed-size pages (NP, Hkv, ps, D):
+# request b's logical token t sits at page page_table[b, t // ps],
+# offset t % ps, and one page holds that token range for every KV head,
+# contiguous in HBM.
+#
+# One grid step does the whole call, so its cost follows the live pages:
+#
+# - Schedule.  The scalar core lists the live blocks, (lane, block) pairs
+#   in lane order, where a block is P pages (P * ps tokens) and lane b
+#   has cdiv(kv_len[b], P * ps) of them.  Lanes with kv_len 0 and blocks
+#   past a lane's last token are never visited; such lanes output zeros.
+# - DMA.  The pools stay in HBM (``pl.ANY``).  Each live page of a block
+#   is fetched by its own copy, all Hkv heads at once, named by the
+#   scalar-prefetched page table, into a VMEM buffer of (Hkv, P * ps, D)
+#   per K and V.  Two buffers: the next block's copies (the next lane's
+#   first block, across lanes) fly while this block computes.  Pages of a
+#   block past the lane's last token are not fetched, so ``-1`` entries
+#   there are never read.
+# - Compute.  q arrives grouped by KV head, (B, Hkv, g, D) with
+#   g = Hq // Hkv: per block and KV head one (g, D) x (D, P * ps) product
+#   serves the g query heads that share the head's pages (GQA; MHA is
+#   g = 1).  Tokens past kv_len are masked, and V's rows there zeroed so
+#   that a buffer's stale rows never reach the sum.  The online-softmax
+#   state per KV head (running max, sum and (g, D) accumulator) is f32 in
+#   VMEM, as are K, V and q inside the products.  The heads run in
+#   unrolled groups of up to _HEAD_GROUP, so that their independent
+#   products overlap; a full unroll of 32 heads only lengthens every
+#   program's lowering.
+#
+# P comes from the shapes alone: as many pages as fit one buffer of
+# _BLOCK_BYTES, at most the page table's width.
+
+_BLOCK_BYTES = 2 << 20
+_HEAD_GROUP = 8
 
 
-def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *,
-                         ps: int, n_pg: int, scale: float):
-    b = pl.program_id(0)
-    pi = pl.program_id(2)
-    kv_len = len_ref[b]
+def pages_per_block(hkv: int, ps: int, d: int, dtype, mp: int) -> int:
+    """P: the pages of one block, as many as fit one K (or V) buffer of
+    ``_BLOCK_BYTES``, at least one and at most the table's ``mp``."""
+    page_bytes = hkv * ps * d * jnp.dtype(dtype).itemsize
+    return max(1, min(mp, _BLOCK_BYTES // page_bytes))
 
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(pi * ps < kv_len)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (1, d)
-        k = k_ref[0, 0].astype(jnp.float32)  # (ps, d)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (1, ps)
-        kpos = pi * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        s = jnp.where(kpos < kv_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sem, acc_ref, m_ref, l_ref,
+                         lane_ref, blk_ref, *, ppb: int, scale: float):
+    n_lanes, mp = pt_ref.shape
+    n_pages, hkv, ps, _ = k_hbm.shape
+    bt = ppb * ps
+    hg = math.gcd(hkv, _HEAD_GROUP)
 
-    @pl.when(pi == n_pg - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+    def live(b):  # tokens lane b attends, at most the table's reach
+        return jnp.minimum(len_ref[b], mp * ps)
+
+    def add_lane(b, n):
+        def add(i, n):
+            lane_ref[n] = b
+            blk_ref[n] = i
+            return n + 1
+        return jax.lax.fori_loop(0, (live(b) + bt - 1) // bt, add, n)
+
+    n_blocks = jax.lax.fori_loop(0, n_lanes, add_lane, 0)
+
+    def each_copy(n, slot, act):
+        """``act`` on the K and V copies of schedule entry n's live
+        pages, into buffer ``slot``."""
+        b, first = lane_ref[n], blk_ref[n] * ppb
+        n_live = jnp.minimum((live(b) + ps - 1) // ps - first, ppb)
+
+        def page_copies(j, _):
+            page = jnp.clip(pt_ref[b, first + j], 0, n_pages - 1)
+            rows = pl.ds(pl.multiple_of(j * ps, ps), ps)
+            for kv, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                             (v_hbm, v_buf))):
+                act(pltpu.make_async_copy(hbm.at[page], buf.at[slot, :, rows],
+                                          sem.at[kv, slot]))
+
+        jax.lax.fori_loop(0, n_live, page_copies, None)
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_blocks > 0)
+    def _():
+        each_copy(0, 0, lambda c: c.start())
+
+    def block(n, _):
+        slot = n % 2
+        b, blk = lane_ref[n], blk_ref[n]
+        kv_len = live(b)
+
+        @pl.when(n + 1 < n_blocks)
+        def _():
+            each_copy(n + 1, 1 - slot, lambda c: c.start())
+
+        @pl.when(blk == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+        each_copy(n, slot, lambda c: c.wait())
+        kpos = blk * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        vpos = blk * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+
+        def heads(i, _):
+            for u in range(hg):  # unrolled: the heads' products overlap
+                h = i * hg + u
+                q = q_ref[b, h].astype(jnp.float32)  # (g, d)
+                k = k_buf[slot, h].astype(jnp.float32)  # (bt, d)
+                v = jnp.where(vpos < kv_len, v_buf[slot, h].astype(jnp.float32),
+                              0.0)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale  # (g, bt)
+                s = jnp.where(kpos < kv_len, s, NEG_INF)
+                m_prev = m_ref[h]  # (g, 1)
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                m_ref[h] = m_new
+                acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+
+        jax.lax.fori_loop(0, hkv // hg, heads, None)
+
+        @pl.when((blk + 1) * bt >= kv_len)
+        def _():
+            denom = jnp.maximum(l_ref[...], 1e-30)
+            o_ref[b] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+    jax.lax.fori_loop(0, n_blocks, block, None)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
                            interpret: bool = False) -> jax.Array:
     """q: (B, Hq, D); k/v_pages: (NP, Hkv, ps, D) with Hq % Hkv == 0
-    (GQA: query head hi reads kv head hi // g through the index map —
-    the shared pool is never replicated); page_table: (B, MP) int32
-    (-1 = unallocated); kv_len: (B,).  Returns (B, Hq, D)."""
+    (GQA: query head hi reads KV head hi // g, g = Hq // Hkv; the shared
+    pool is never replicated); page_table: (B, MP) int32 (-1 =
+    unallocated; entries past a lane's kv_len are never read); kv_len:
+    (B,).  Returns (B, Hq, D)."""
     b, h, d = q.shape
-    n_pages, hkv, ps, _ = k_pages.shape
+    _, hkv, ps, _ = k_pages.shape
     assert h % hkv == 0, (h, hkv)
     g = h // hkv
     mp = page_table.shape[1]
-    scale = 1.0 / (d ** 0.5)
-    q4 = q[:, :, None, :]  # (B, Hq, 1, D)
-    # Unallocated entries are masked via kv_len; clamp so the index map
-    # still names a real page.
-    pt = jnp.clip(page_table, 0, n_pages - 1).astype(jnp.int32)
+    ppb = pages_per_block(hkv, ps, d, k_pages.dtype, mp)
+    bt = ppb * ps
+    n_sched = b * (-(-mp // ppb))
 
     kernel = functools.partial(
-        _paged_decode_kernel, ps=ps, n_pg=mp, scale=scale
+        _paged_decode_kernel, ppb=ppb, scale=1.0 / (d ** 0.5)
     )
+    whole = pl.BlockSpec((b, hkv, g, d), lambda i, pt, lens: (0, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, mp),
+        grid=(1,),
         in_specs=[
-            pl.BlockSpec(
-                (1, 1, 1, d), lambda bi, hi, pi, pt, lens: (bi, hi, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, ps, d),
-                lambda bi, hi, pi, pt, lens: (pt[bi, pi], hi // g, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, ps, d),
-                lambda bi, hi, pi, pt, lens: (pt[bi, pi], hi // g, 0, 0),
-            ),
+            whole,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, 1, d), lambda bi, hi, pi, pt, lens: (bi, hi, 0, 0)
-        ),
+        out_specs=whole,
         scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((2, hkv, bt, d), k_pages.dtype),
+            pltpu.VMEM((2, hkv, bt, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((hkv, g, d), jnp.float32),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.SMEM((n_sched,), jnp.int32),
+            pltpu.SMEM((n_sched,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
-    )(pt, kv_len.astype(jnp.int32), q4, k_pages, v_pages)
-    return out[:, :, 0, :]
+    )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
+      q.reshape(b, hkv, g, d), k_pages, v_pages)
+    return out.reshape(b, h, d)

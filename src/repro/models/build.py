@@ -258,8 +258,8 @@ class Model:
             kv_len = positions[:, 0] + lens
             if s == 1 and ops.kernels_enabled():
                 # the one kernel decision (see repro.kernels.ops); GQA
-                # is handled inside the kernel's index map — the page
-                # pool stays at Hkv heads, never replicated
+                # is handled inside the kernel — a KV head's query heads
+                # read its pages together, the pool stays at Hkv heads
                 ctx = ops.paged_decode_attention(
                     q[:, :, 0, :], kp, vp, page_table, kv_len,
                 )[:, :, None, :]

@@ -77,10 +77,14 @@ def _assert_fits(compiled) -> None:
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_kernel_compiles(one_chip, dtype):
-    pages = _spec((B * MP, HKV, PS, D), dtype, one_chip)
+@pytest.mark.parametrize("hq,hkv", [(HQ, HKV), (40, 8)],
+                         ids=["qwen7b", "qwen2.5-14b"])
+def test_paged_decode_kernel_compiles(one_chip, hq, hkv, dtype):
+    """Both benchmark configurations' heads (MHA 32/32, GQA 40/8) at the
+    served pool, table and head size."""
+    pages = _spec((B * MP, hkv, PS, D), dtype, one_chip)
     c = jax.jit(paged_decode_attention).lower(
-        _spec((B, HQ, D), dtype, one_chip), pages, pages,
+        _spec((B, hq, D), dtype, one_chip), pages, pages,
         _spec((B, MP), jnp.int32, one_chip), _spec((B,), jnp.int32, one_chip),
     ).compile()
     assert "tpu_custom_call" in c.as_text()

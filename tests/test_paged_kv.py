@@ -7,7 +7,10 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import paged_decode_attention
+from repro.kernels.decode_attention import (
+    paged_decode_attention,
+    pages_per_block,
+)
 from repro.serving.kv_manager import PageAllocator, PagedKVManager
 
 
@@ -91,11 +94,14 @@ def test_kv_manager_tables_disjoint():
 # ---------------------------------------------------------------------------
 
 
-def _paged_fixture(seed, b, h, s, d, ps):
-    """Build a contiguous cache and its paged twin via a PagedKVManager."""
+def _paged_fixture(seed, b, h, s, d, ps, kv_len=None):
+    """Build a contiguous cache and its paged twin via a PagedKVManager
+    (``kv_len`` drawn from ``seed`` unless given)."""
     rng = np.random.default_rng(seed)
     kv = PagedKVManager(n_slots=b, max_len=s, page_size=ps)
-    kv_len = rng.integers(1, s + 1, size=b).astype(np.int32)
+    if kv_len is None:
+        kv_len = rng.integers(1, s + 1, size=b)
+    kv_len = np.asarray(kv_len, np.int32)
     k_cont = rng.standard_normal((b, h, s, d)).astype(np.float32)
     v_cont = rng.standard_normal((b, h, s, d)).astype(np.float32)
     k_pages = np.zeros((kv.n_pages, h, ps, d), np.float32)
@@ -111,6 +117,36 @@ def _paged_fixture(seed, b, h, s, d, ps):
     return kv, map(jnp.asarray, (k_cont, v_cont, k_pages, v_pages, kv_len))
 
 
+def _poison_dead(kv, k_pages, v_pages, kv_len):
+    """NaN in every slot of the pool but the live tokens, plus one NaN
+    page that every table entry past a lane's live pages names: the dead
+    tail of each last page, whole blocks past it and idle lanes' tables
+    all point at garbage that must not reach the output."""
+    ps = kv.page_size
+    live = np.zeros((kv.n_pages + 1, 1, ps, 1), bool)
+    table = kv.table.copy()
+    for i, n in enumerate(np.asarray(kv_len)):
+        for t in range(int(n)):
+            live[table[i, t // ps], 0, t % ps, 0] = True
+        table[i, -(-int(n) // ps):] = kv.n_pages
+    live = jnp.asarray(live)
+
+    def poison(pages):
+        pages = jnp.concatenate([pages, jnp.zeros_like(pages[:1])])
+        return jnp.where(live, pages, jnp.nan)
+
+    return poison(k_pages), poison(v_pages), jnp.asarray(table)
+
+
+def _check_lanes(got, want, kv_len):
+    """Live lanes match the reference; idle lanes (kv_len 0) are zero."""
+    on = np.asarray(kv_len) > 0
+    got = np.asarray(got)
+    np.testing.assert_allclose(got[on], np.asarray(want)[on],
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(got[~on], 0.0)
+
+
 def test_page_table_gather_matches_contiguous():
     b, h, s, d, ps = 3, 2, 32, 16, 8
     kv, (k_cont, _, k_pages, _, kv_len) = _paged_fixture(0, b, h, s, d, ps)
@@ -122,27 +158,58 @@ def test_page_table_gather_matches_contiguous():
         )
 
 
-@pytest.mark.parametrize("ps", [4, 8, 16])
-def test_paged_decode_attention_matches_contiguous_ref(ps):
-    b, h, s, d = 3, 2, 32, 16
+# The served paged plane, as both benchmark configurations run it: 8
+# lanes of 1024 tokens in 16-token pages, head size 128.
+SERVED_B, SERVED_S, SERVED_D, SERVED_PS = 8, 1024, 128, 16
+
+
+def _served_lens(hkv):
+    """A lane per boundary: idle (its table all -1), one token, a page,
+    a page and one, a block, a block and one, the whole table, and two
+    blocks and a part."""
+    bt = SERVED_PS * pages_per_block(hkv, SERVED_PS, SERVED_D, jnp.float32,
+                                     SERVED_S // SERVED_PS)
+    return [0, 1, SERVED_PS, SERVED_PS + 1, bt, bt + 1, SERVED_S,
+            min(2 * bt + 5, SERVED_S - 1)]
+
+
+@pytest.mark.parametrize("shape", [
+    *(pytest.param((3, 2, 2, 32, 16, ps), id=str(ps)) for ps in (4, 8, 16)),
+    *(pytest.param((SERVED_B, hq, hkv, SERVED_S, SERVED_D, SERVED_PS),
+                   id=f"served-{hq}-{hkv}") for hq, hkv in ((32, 32), (40, 8))),
+])
+def test_paged_decode_attention_matches_contiguous_ref(shape):
+    """Small pools with drawn lengths, and the served shapes (MHA 32/32
+    and GQA 40/8) with a lane at each block boundary over a pool
+    poisoned outside the live tokens."""
+    b, hq, hkv, s, d, ps = shape
+    served = b == SERVED_B
     kv, (k_cont, v_cont, k_pages, v_pages, kv_len) = _paged_fixture(
-        ps, b, h, s, d, ps
+        ps, b, hkv, s, d, ps, _served_lens(hkv) if served else None
     )
-    q = jax.random.normal(jax.random.key(7), (b, h, d))
-    want = ref.decode_attention_ref(q, k_cont, v_cont, kv_len)
+    q = jax.random.normal(jax.random.key(7), (b, hq, d))
+    g = hq // hkv
+    want = ref.decode_attention_ref(
+        q, jnp.repeat(k_cont, g, axis=1), jnp.repeat(v_cont, g, axis=1),
+        kv_len,
+    )
     pt = jnp.asarray(kv.table)
     got_ref = ref.paged_decode_attention_ref(q, k_pages, v_pages, pt, kv_len)
+    on = np.asarray(kv_len) > 0  # the oracle's idle lanes average page 0
+    np.testing.assert_allclose(np.asarray(got_ref)[on], np.asarray(want)[on],
+                               rtol=2e-5, atol=2e-5)
+    if served:
+        k_pages, v_pages, pt = _poison_dead(kv, k_pages, v_pages, kv_len)
+        pt = pt.at[0].set(-1)  # the idle lane's table all -1
     got_pl = paged_decode_attention(q, k_pages, v_pages, pt, kv_len,
                                     interpret=True)
-    np.testing.assert_allclose(np.asarray(got_ref), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(got_pl), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+    _check_lanes(got_pl, want, kv_len)
 
 
 def test_paged_decode_attention_gqa_heads():
-    """Hq > Hkv: the kernel maps query head hi to kv head hi // g via
-    the index map — must match the broadcast contiguous reference."""
+    """Hq > Hkv: query head hi reads KV head hi // g (the kernel serves a
+    KV head's g query heads together) — must match the broadcast
+    contiguous reference."""
     b, hq, hkv, s, d, ps = 2, 6, 2, 16, 16, 4
     kv, (k_cont, v_cont, k_pages, v_pages, kv_len) = _paged_fixture(
         11, b, hkv, s, d, ps
@@ -164,25 +231,19 @@ def test_paged_decode_attention_gqa_heads():
 
 
 def test_paged_kernel_ignores_stale_pages():
-    """Reclaimed-page garbage beyond kv_len must not leak into outputs."""
-    b, h, s, d, ps = 2, 2, 16, 8, 4
+    """Reclaimed-page garbage must not leak into outputs: NaN at every
+    allocated-but-unused offset, in all free pages, and behind every
+    table entry past a lane's live pages (an idle lane's too)."""
+    b, h, s, d, ps = 3, 2, 16, 8, 4
     kv, (k_cont, v_cont, k_pages, v_pages, kv_len) = _paged_fixture(
-        3, b, h, s, d, ps
+        3, b, h, s, d, ps, kv_len=[6, 13, 0]
     )
-    # poison every allocated-but-unused offset and all free pages
-    poison = 1e3 * jnp.ones_like(k_pages)
-    mask = np.zeros((kv.n_pages, 1, ps, 1), bool)
-    for i in range(b):
-        for t in range(int(kv_len[i])):
-            mask[kv.table[i, t // ps], 0, t % ps, 0] = True
-    k_pois = jnp.where(jnp.asarray(mask), k_pages, poison)
-    v_pois = jnp.where(jnp.asarray(mask), v_pages, poison)
+    k_pois, v_pois, pt = _poison_dead(kv, k_pages, v_pages, kv_len)
     q = jax.random.normal(jax.random.key(9), (b, h, d))
     want = ref.decode_attention_ref(q, k_cont, v_cont, kv_len)
-    got = paged_decode_attention(q, k_pois, v_pois, jnp.asarray(kv.table),
-                                 kv_len, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+    got = paged_decode_attention(q, k_pois, v_pois, pt, kv_len,
+                                 interpret=True)
+    _check_lanes(got, want, kv_len)
 
 
 # ---------------------------------------------------------------------------
