@@ -6,6 +6,8 @@ pattern* used by the generic model builders in ``repro.models``:
 
 - ``dense``        — standard pre-norm GQA transformer block
 - ``moe``          — GQA attention + top-k mixture-of-experts FFN
+- ``mla``          — latent attention (MLA) + mixture-of-experts FFN
+- ``mla_dense``    — latent attention (MLA) + dense SwiGLU FFN
 - ``mamba``        — Mamba-2 SSD block (attention free)
 - ``local``        — sliding-window (local) GQA attention block
 - ``global``       — full (global) GQA attention block
@@ -57,15 +59,60 @@ SHAPES: dict[str, ShapeSpec] = {
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    """A dropless top-k expert layer.
+
+    The router scores all ``num_experts``; this chip holds the
+    ``n_held`` experts from ``held_offset`` on (0 = all of them) and
+    computes every assignment to them, with no capacity.  What the
+    experts held elsewhere would add is not computed here: under expert
+    parallelism it arrives from the chips that hold them.
+    """
+
+    num_experts: int    # router width: every expert is routed over
     top_k: int
     expert_d_ff: int
-    capacity_factor: float = 1.25
-    # 0 = global sort-based dispatch; >0 = dispatch within token groups
-    # (groups aligned to the data shards), which removes the global
-    # argsort/gather collectives at the cost of per-group capacity
-    # imbalance — the classic grouped-MoE trade.
-    dispatch_groups: int = 0
+    # "softmax": weights are the chosen softmax probabilities;
+    # "sigmoid": DeepSeek-V3's noaux_tc, selection on sigmoid scores
+    # plus a per-expert bias, weights from the unbiased scores
+    router: str = "softmax"
+    routed_scaling: float = 1.0     # scales the k renormalized weights
+    # shared experts, run for every token and added ungated: one SwiGLU
+    # of width n_shared * expert_d_ff
+    n_shared: int = 0
+    n_held: int = 0
+    held_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3).  Each token keeps
+    one ``kv_lora_rank`` latent and one ``qk_rope_head_dim`` rotary key
+    shared by all heads; keys and values are per-head up-projections of
+    the latent."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def pool_width(self) -> int:
+        """A cached row: the latent and the rotary key, zero-padded to
+        the TPU's 128-lane tiling (576 -> 640), which a page's DMA
+        needs."""
+        return -(-self.latent_dim // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,6 +155,16 @@ class ModelConfig:
     # MoE / SSM extensions.
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    # Latent attention in every attention block; the first
+    # ``first_dense`` layers carry a dense FFN of width d_ff, the rest
+    # the MoE (DeepSeek-V3's first_k_dense_replace).
+    mla: Optional[MLAConfig] = None
+    first_dense: int = 0
+    # Precision of every matmul the model's programs trace
+    # (``jax.default_matmul_precision``); None keeps JAX's default, one
+    # bf16 pass on a TPU.  A router that picks experts on near-ties
+    # needs "highest": one bf16 pass upstream flips its choices.
+    matmul_precision: Optional[str] = None
     # Hybrid (zamba2): one weight-shared attention block invoked every
     # ``shared_attn_period`` SSM layers.
     shared_attn_period: int = 0
@@ -126,6 +183,14 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // self.n_heads if self.n_heads else 0
+
+    @property
+    def kv_width(self) -> int:
+        """Cached values per token and attention layer: K and V of every
+        KV head, or latent attention's one latent and rotary key."""
+        if self.mla is not None:
+            return self.mla.latent_dim
+        return 2 * self.n_kv_heads * self.resolved_head_dim
 
     @property
     def attention_free(self) -> bool:
@@ -177,6 +242,12 @@ class ModelConfig:
             if tail:
                 segs.append(("local", tail))
             return tuple(segs)
+        if self.mla is not None:
+            if self.moe is None:
+                return (("mla_dense", self.n_layers),)
+            segs = [("mla_dense", self.first_dense),
+                    ("mla", self.n_layers - self.first_dense)]
+            return tuple((k, c) for k, c in segs if c)
         if self.moe is not None:
             return (("moe", self.n_layers),)
         if self.is_encoder_only:
@@ -184,8 +255,31 @@ class ModelConfig:
         return (("dense", self.n_layers),)
 
     # -- parameter count (exact, from shapes) ------------------------------
+    def _mla_attn_params(self) -> int:
+        a, d, h = self.mla, self.d_model, self.n_heads
+        q = d * h * a.qk_head_dim
+        kv = d * a.latent_dim + a.kv_lora_rank + a.kv_lora_rank * h * (
+            a.qk_nope_head_dim + a.v_head_dim)
+        return q + kv + h * a.v_head_dim * d
+
+    def _moe_ffn_params(self, held: bool = False) -> int:
+        m, d = self.moe, self.d_model
+        n = m.held if held else m.num_experts
+        router = d * m.num_experts + (m.num_experts
+                                      if m.router == "sigmoid" else 0)
+        shared = 3 * d * m.n_shared * m.expert_d_ff
+        return router + n * 3 * d * m.expert_d_ff + shared
+
     def param_count(self) -> int:
         d, hd = self.d_model, self.resolved_head_dim
+        if self.mla is not None:
+            embed = self.vocab_size * d * (1 if self.tie_embeddings else 2)
+            body = 0
+            for kind, count in self.layer_pattern():
+                ffn = (self._moe_ffn_params() if kind == "mla"
+                       else 3 * d * self.d_ff)
+                body += count * (self._mla_attn_params() + ffn + 2 * d)
+            return body + embed + d
         q_dim = self.n_heads * hd
         kv_dim = self.n_kv_heads * hd
         attn = d * q_dim + 2 * d * kv_dim + q_dim * d  # wq, wk, wv, wo
@@ -236,8 +330,9 @@ class ModelConfig:
             return self.param_count()
         m = self.moe
         d = self.d_model
-        full_ffn = self.n_layers * m.num_experts * 3 * d * m.expert_d_ff
-        act_ffn = self.n_layers * m.top_k * 3 * d * m.expert_d_ff
+        n_moe = sum(c for k, c in self.layer_pattern() if k in ("moe", "mla"))
+        full_ffn = n_moe * m.num_experts * 3 * d * m.expert_d_ff
+        act_ffn = n_moe * m.top_k * 3 * d * m.expert_d_ff
         return self.param_count() - full_ffn + act_ffn
 
     def shapes(self) -> Sequence[ShapeSpec]:
@@ -270,11 +365,19 @@ def reduce_config(cfg: ModelConfig) -> ModelConfig:
     """Shrink a config to a CPU-runnable smoke variant of the same family."""
     moe = None
     if cfg.moe is not None:
-        moe = MoEConfig(
+        moe = dataclasses.replace(
+            cfg.moe,
             num_experts=min(cfg.moe.num_experts, 8),
             top_k=min(cfg.moe.top_k, 2),
             expert_d_ff=64,
-            capacity_factor=cfg.moe.capacity_factor,
+            n_held=min(cfg.moe.n_held, 4),
+            held_offset=0,
+        )
+    mla = None
+    if cfg.mla is not None:
+        mla = dataclasses.replace(
+            cfg.mla, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16,
         )
     ssm = None
     if cfg.ssm is not None:
@@ -291,6 +394,8 @@ def reduce_config(cfg: ModelConfig) -> ModelConfig:
         n_layers, period = 7, 2  # 2 groups of (2 mamba + 1 shared) + 1 tail
     elif cfg.local_global_ratio > 0:
         n_layers = (cfg.local_global_ratio + 1) + 1  # one group + tail
+    elif cfg.mla is not None and cfg.first_dense:
+        n_layers = min(cfg.first_dense, 1) + 2
     else:
         n_layers = 2
     return dataclasses.replace(
@@ -306,6 +411,8 @@ def reduce_config(cfg: ModelConfig) -> ModelConfig:
         window=8 if cfg.window else 0,
         moe=moe,
         ssm=ssm,
+        mla=mla,
+        first_dense=min(cfg.first_dense, 1),
         shared_attn_period=2 if cfg.family == "hybrid" else 0,
     )
 
@@ -332,7 +439,8 @@ def mfu_flops(cfg: ModelConfig, shape: ShapeSpec) -> float:
     attn = 0.0
     hd = cfg.resolved_head_dim
     for kind, count in cfg.layer_pattern():
-        if kind in ("dense", "moe", "global", "encoder", "shared_attn"):
+        if kind in ("dense", "moe", "global", "encoder", "shared_attn",
+                    "mla", "mla_dense"):
             s_eff = shape.seq_len
         elif kind == "local":
             s_eff = min(cfg.window, shape.seq_len)

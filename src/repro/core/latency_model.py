@@ -134,12 +134,11 @@ class AnalyticLatencyModel(LatencyModel):
 
     @staticmethod
     def _kv_bytes_per_token(cfg: ModelConfig, dtype_bytes: int) -> float:
-        hd = cfg.resolved_head_dim
         total = 0.0
         for kind, cnt in cfg.layer_pattern():
             if kind == "mamba":
                 continue  # O(1) state: no per-token KV growth
-            total += cnt * 2 * cfg.n_kv_heads * hd * dtype_bytes
+            total += cnt * cfg.kv_width * dtype_bytes
         return total
 
 

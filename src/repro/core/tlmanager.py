@@ -34,7 +34,6 @@ class TransferCosts:
 
 def kv_bytes(cfg: ModelConfig, tokens: int, dtype_bytes: int = 2) -> float:
     """KV-cache footprint of `tokens` cached tokens (SSM: fixed state)."""
-    hd = cfg.resolved_head_dim
     total = 0.0
     for kind, cnt in cfg.layer_pattern():
         if kind == "mamba":
@@ -49,7 +48,7 @@ def kv_bytes(cfg: ModelConfig, tokens: int, dtype_bytes: int = 2) -> float:
                     + h * s.head_dim * s.d_state * 4
                 )
         else:
-            total += cnt * 2 * cfg.n_kv_heads * hd * tokens * dtype_bytes
+            total += cnt * cfg.kv_width * tokens * dtype_bytes
     return total
 
 

@@ -146,7 +146,6 @@ def baseline_rules(mesh: Mesh, *, fsdp: bool = True,
         "kv_fused": "model",
         "qblocks": None,
         "cache_seq": None,
-        "moe_group": None,
     }
     return AxisRules(mapping, mesh)
 
@@ -223,15 +222,6 @@ def arch_rules(cfg, mesh: Mesh, *, stage: str = "train",
     if cfg.moe is not None:
         mp["experts"] = ("model" if cfg.moe.num_experts % m == 0 else None)
         mp["ff"] = ("model" if cfg.moe.expert_d_ff % m == 0 else mp["ff"])
-        if cfg.moe.dispatch_groups:
-            # grouped dispatch: groups shard over data AND model; expert
-            # compute is fully shard-local, expert weights are gathered
-            # (ZeRO-style, fsdp axis) instead of tokens being scattered
-            base = mp["batch"] if isinstance(mp["batch"], tuple) else (
-                (mp["batch"],) if mp["batch"] else ())
-            mp["moe_group"] = tuple(base) + ("model",)
-            mp["experts"] = None
-            mp["ff"] = None
     if cfg.ssm is not None:
         di = cfg.ssm.d_inner(cfg.d_model)
         h = cfg.ssm.n_heads(cfg.d_model)

@@ -157,15 +157,20 @@ def pages_per_block(hkv: int, ps: int, d: int, dtype, mp: int) -> int:
     return max(1, min(mp, _BLOCK_BYTES // page_bytes))
 
 
-def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sem, acc_ref, m_ref, l_ref,
-                         lane_ref, blk_ref, *, ppb: int, scale: float):
+def _live_blocks(pt_ref, len_ref, lane_ref, blk_ref, pools, bufs, sem, *,
+                 ppb: int):
+    """The schedule both paged decode kernels walk: fills ``lane_ref``
+    and ``blk_ref`` with the live (lane, block) pairs in lane order and
+    returns ``(n_blocks, live, each_copy)``.  ``live(b)`` is the tokens
+    lane b attends, at most the table's reach; ``each_copy(n, slot,
+    act)`` calls ``act`` on the copies of schedule entry n's live pages,
+    one per pool in ``pools`` (HBM, (NP, H, ps, D)), into buffer
+    ``slot`` of the matching VMEM buffer in ``bufs``."""
     n_lanes, mp = pt_ref.shape
-    n_pages, hkv, ps, _ = k_hbm.shape
+    n_pages, _, ps, _ = pools[0].shape
     bt = ppb * ps
-    hg = math.gcd(hkv, _HEAD_GROUP)
 
-    def live(b):  # tokens lane b attends, at most the table's reach
+    def live(b):
         return jnp.minimum(len_ref[b], mp * ps)
 
     def add_lane(b, n):
@@ -178,20 +183,30 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     n_blocks = jax.lax.fori_loop(0, n_lanes, add_lane, 0)
 
     def each_copy(n, slot, act):
-        """``act`` on the K and V copies of schedule entry n's live
-        pages, into buffer ``slot``."""
         b, first = lane_ref[n], blk_ref[n] * ppb
         n_live = jnp.minimum((live(b) + ps - 1) // ps - first, ppb)
 
         def page_copies(j, _):
             page = jnp.clip(pt_ref[b, first + j], 0, n_pages - 1)
             rows = pl.ds(pl.multiple_of(j * ps, ps), ps)
-            for kv, (hbm, buf) in enumerate(((k_hbm, k_buf),
-                                             (v_hbm, v_buf))):
+            for i, (hbm, buf) in enumerate(zip(pools, bufs)):
                 act(pltpu.make_async_copy(hbm.at[page], buf.at[slot, :, rows],
-                                          sem.at[kv, slot]))
+                                          sem.at[i, slot]))
 
         jax.lax.fori_loop(0, n_live, page_copies, None)
+
+    return n_blocks, live, each_copy
+
+
+def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sem, acc_ref, m_ref, l_ref,
+                         lane_ref, blk_ref, *, ppb: int, scale: float):
+    hkv, ps = k_hbm.shape[1], k_hbm.shape[2]
+    bt = ppb * ps
+    hg = math.gcd(hkv, _HEAD_GROUP)
+    n_blocks, live, each_copy = _live_blocks(
+        pt_ref, len_ref, lane_ref, blk_ref, (k_hbm, v_hbm), (k_buf, v_buf),
+        sem, ppb=ppb)
 
     o_ref[...] = jnp.zeros_like(o_ref)
 
@@ -300,3 +315,124 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
       q.reshape(b, hkv, g, d), k_pages, v_pages)
     return out.reshape(b, h, d)
+
+
+# ---------------------------------------------------------------------------
+# Paged MLA decode attention (latent pool)
+# ---------------------------------------------------------------------------
+#
+# Latent attention's decode in the absorbed form (``models/attention.py``)
+# is MQA over one latent "head": the pool (NP, 1, ps, W) holds each
+# token's [c, k_r], zero-padded to W, a multiple of 128 lanes (a page's
+# DMA refuses a narrower last dim); every query head scores the whole
+# row with its [q_nope W_UK^T, q_rope, 0], and reads the row's first kv_lora
+# columns back as its value.  The schedule, DMAs and double buffers are
+# those of ``paged_decode_attention`` (g = Hq, Hkv = 1, and V the same
+# buffer as K): each live page is fetched once per lane, and no page is
+# ever expanded per head.  Per block: scores (Hq, P*ps), one online-
+# softmax state per query head, and the (Hq, kv_lora) accumulator.
+
+
+def _paged_mla_kernel(pt_ref, len_ref, q_ref, hbm, o_ref, buf, sem,
+                      acc_ref, m_ref, l_ref, lane_ref, blk_ref, *,
+                      ppb: int, scale: float):
+    ps = hbm.shape[2]
+    lora = o_ref.shape[-1]
+    bt = ppb * ps
+    n_blocks, live, each_copy = _live_blocks(
+        pt_ref, len_ref, lane_ref, blk_ref, (hbm,), (buf,), sem, ppb=ppb)
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_blocks > 0)
+    def _():
+        each_copy(0, 0, lambda c: c.start())
+
+    def block(n, _):
+        slot = n % 2
+        b, blk = lane_ref[n], blk_ref[n]
+        kv_len = live(b)
+
+        @pl.when(n + 1 < n_blocks)
+        def _():
+            each_copy(n + 1, 1 - slot, lambda c: c.start())
+
+        @pl.when(blk == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+        each_copy(n, slot, lambda c: c.wait())
+        kpos = blk * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        vpos = blk * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+        rows = buf[slot, 0].astype(jnp.float32)         # (bt, W)
+        s = jax.lax.dot_general(
+            q_ref[b].astype(jnp.float32), rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                       # (Hq, bt)
+        s = jnp.where(kpos < kv_len, s, NEG_INF)
+        v = jnp.where(vpos < kv_len, rows[:, :lora], 0.0)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+        @pl.when((blk + 1) * bt >= kv_len)
+        def _():
+            denom = jnp.maximum(l_ref[...], 1e-30)
+            o_ref[b] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+    jax.lax.fori_loop(0, n_blocks, block, None)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_mla_decode_attention(q_c, q_r, pages, page_table, kv_len, *,
+                               scale: float,
+                               interpret: bool = False) -> jax.Array:
+    """q_c: (B, Hq, L) absorbed queries, q_r: (B, Hq, R) rotary queries;
+    pages: (NP, 1, ps, W), each token's [latent, rotary key] zero-padded
+    to W >= L + R, a multiple of 128;
+    page_table: (B, MP) int32 (-1 = unallocated; entries past a lane's
+    kv_len are never read); kv_len: (B,).  Returns (B, Hq, L): each
+    head's softmax-weighted latents."""
+    b, h, lora = q_c.shape
+    _, _, ps, width = pages.shape
+    pad = jnp.zeros((b, h, width - lora - q_r.shape[-1]), q_c.dtype)
+    q = jnp.concatenate([q_c, q_r, pad], axis=-1)
+    mp = page_table.shape[1]
+    ppb = pages_per_block(1, ps, width, pages.dtype, mp)
+    bt = ppb * ps
+    n_sched = b * (-(-mp // ppb))
+
+    kernel = functools.partial(_paged_mla_kernel, ppb=ppb, scale=scale)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(1,),
+        in_specs=[
+            pl.BlockSpec((b, h, width), lambda i, pt, lens: (0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((b, h, lora), lambda i, pt, lens: (0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, 1, bt, width), pages.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.VMEM((h, lora), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.SMEM((n_sched,), jnp.int32),
+            pltpu.SMEM((n_sched,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, lora), q_c.dtype),
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32), q, pages)

@@ -1,6 +1,6 @@
 """The one kernel decision: compiled Pallas kernels on TPU, jnp elsewhere.
 
-Two kernels sit on the served path, and both run compiled exactly when
+Three kernels sit on the served path, and all run compiled exactly when
 JAX's default backend is a TPU:
 
 - ``paged_decode_attention`` — the paged plane's one-token decode
@@ -9,6 +9,9 @@ JAX's default backend is a TPU:
   fetching each live page (all KV heads) by its own DMA, double-
   buffered, and serves a KV head's query heads together; idle lanes
   and pages past a lane's length are never read;
+- ``paged_mla_decode_attention`` — its latent-attention twin
+  (``Model._mla_block``): the same schedule over the latent pools,
+  all query heads scoring one shared latent per token;
 - ``page_gather`` — linearizing a request's pages for the P/D hand-off
   (``kv_manager.gather_slot_kv``).
 
@@ -26,7 +29,10 @@ from __future__ import annotations
 import jax
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import paged_decode_attention
+from repro.kernels.decode_attention import (
+    paged_decode_attention,
+    paged_mla_decode_attention,
+)
 from repro.kernels.page_gather import page_gather as _pl_page_gather
 
 
@@ -41,4 +47,5 @@ def page_gather(pages, page_ids):
     return ref.page_gather_ref(pages, page_ids)
 
 
-__all__ = ["kernels_enabled", "page_gather", "paged_decode_attention"]
+__all__ = ["kernels_enabled", "page_gather", "paged_decode_attention",
+           "paged_mla_decode_attention"]

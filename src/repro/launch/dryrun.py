@@ -335,7 +335,7 @@ def analyze_terms(rec: dict, cfg: ModelConfig, shape: ShapeSpec,
 
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str,
-             pad_heads: int = 0, moe_groups: int = 0, **kw) -> dict:
+             pad_heads: int = 0, **kw) -> dict:
     import dataclasses as dc
 
     cfg = get_config(arch)
@@ -343,15 +343,10 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     kw.pop("unroll", None)  # phase-controlled inside measure_cell
 
-    # hillclimb knobs: pad attention heads to the TP degree / grouped
-    # MoE dispatch (groups aligned with the data shards)
+    # hillclimb knob: pad attention heads to the TP degree
     run_cfg = cfg
     if pad_heads:
         run_cfg = dc.replace(run_cfg, n_heads=cfg.n_heads + pad_heads)
-    if moe_groups and cfg.moe is not None:
-        run_cfg = dc.replace(
-            run_cfg, moe=dc.replace(cfg.moe, dispatch_groups=moe_groups)
-        )
     rec = {
         "arch": arch, "shape": shape_name, "mesh": mesh_kind,
         "mesh_shape": dict(zip(mesh.axis_names,
@@ -360,8 +355,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     }
     if pad_heads:
         rec["options"]["pad_heads"] = pad_heads
-    if moe_groups:
-        rec["options"]["moe_groups"] = moe_groups
     t0 = time.time()
     try:
         rec.update(measure_cell(run_cfg, shape, mesh, **kw))
@@ -391,7 +384,6 @@ def main() -> None:
     ap.add_argument("--no-residual-shard", action="store_true")
     ap.add_argument("--q-chunk", type=int, default=512)
     ap.add_argument("--pad-heads", type=int, default=0)
-    ap.add_argument("--moe-groups", type=int, default=0)
     ap.add_argument("--train-kv-repeat", action="store_true",
                     help="repeat KV heads to the TP degree in train "
                          "cells (fixes uneven GQA head sharding)")
@@ -426,7 +418,6 @@ def main() -> None:
         q_chunk=args.q_chunk,
         unroll=not args.scan_layers,
         pad_heads=args.pad_heads,
-        moe_groups=args.moe_groups,
         train_kv_repeat=args.train_kv_repeat,
     )
     n_ok = n_fail = 0

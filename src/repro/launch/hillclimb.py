@@ -9,7 +9,7 @@ exact loop EXPERIMENTS.md §Perf records, automated:
         --variants no-fsdp,q-chunk=2048
 
 Known variant knobs: no-fsdp, no-remat, no-residual-shard, compress,
-train-kv-repeat, q-chunk=<n>, pad-heads=<n>, moe-groups=<n>.
+train-kv-repeat, q-chunk=<n>, pad-heads=<n>.
 """
 
 # Must precede any other import (jax locks device count on first init).
@@ -43,8 +43,6 @@ def parse_variant(spec: str) -> dict:
             kw["q_chunk"] = int(part.split("=")[1])
         elif part.startswith("pad-heads="):
             kw["pad_heads"] = int(part.split("=")[1])
-        elif part.startswith("moe-groups="):
-            kw["moe_groups"] = int(part.split("=")[1])
         else:
             raise ValueError(f"unknown variant knob {part!r}")
     return kw

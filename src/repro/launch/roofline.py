@@ -13,7 +13,7 @@ from collections import OrderedDict
 _DEFAULT_OPTIONS = {
     "compress": False, "fsdp": True, "remat": True,
     "shard_residual": None, "q_chunk": 512, "unroll": True,
-    "pad_heads": 0, "moe_groups": 0, "train_kv_repeat": False,
+    "pad_heads": 0, "train_kv_repeat": False,
 }
 
 

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
-from repro.models.common import apply_rope, init_dense, split_keys
+from repro.models.common import apply_rope, init_dense, rms_norm, split_keys
 
 NEG_INF = -1e30
 
@@ -241,14 +241,7 @@ def update_paged_cache(k_pages, v_pages, page_table, k_new, v_new,
     positions; valid: (B, C) bool — invalid rows (chunk padding, idle
     slots) are dropped by scattering out of bounds.
     """
-    ps = k_pages.shape[2]
-    logical = positions // ps                       # (B, C) page index
-    off = positions % ps
-    phys = jnp.take_along_axis(
-        page_table, jnp.clip(logical, 0, page_table.shape[1] - 1), axis=1
-    )
-    # invalid writes -> page id NP (out of bounds, dropped by scatter)
-    phys = jnp.where(valid & (phys >= 0), phys, k_pages.shape[0])
+    phys, off = _page_slots(k_pages.shape, page_table, positions, valid)
     kv = k_new.transpose(0, 2, 1, 3)                # (B, C, H, hd)
     vv = v_new.transpose(0, 2, 1, 3)
     k_pages = k_pages.at[phys, :, off, :].set(
@@ -258,6 +251,21 @@ def update_paged_cache(k_pages, v_pages, page_table, k_new, v_new,
         vv.astype(v_pages.dtype), mode="drop"
     )
     return k_pages, v_pages
+
+
+def _page_slots(pool_shape, page_table, positions, valid):
+    """(physical page, offset) of each chunk token in a pool of
+    ``pool_shape`` (NP, H, ps, hd); invalid rows (chunk padding, idle
+    slots) get page NP, out of bounds, so a ``mode="drop"`` scatter
+    drops them."""
+    ps = pool_shape[2]
+    logical = positions // ps                       # (B, C) page index
+    off = positions % ps
+    phys = jnp.take_along_axis(
+        page_table, jnp.clip(logical, 0, page_table.shape[1] - 1), axis=1
+    )
+    phys = jnp.where(valid & (phys >= 0), phys, pool_shape[0])
+    return phys, off
 
 
 def paged_chunk_attention(q, k_pages, v_pages, page_table, *, q_pos,
@@ -324,3 +332,179 @@ def update_cache(k_cache, v_cache, kv_pos, k_new, v_new, pos, *,
     v_cache = v_cache.at[bidx, :, slot, :].set(v_new[:, :, 0, :])
     kv_pos = kv_pos.at[bidx, slot].set(pos)
     return k_cache, v_cache, kv_pos
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (MLA, DeepSeek-V2/V3)
+# ---------------------------------------------------------------------------
+#
+# Per token the cache keeps one latent c (kv_lora_rank wide, RMS-normed)
+# and one rotary key k_r (qk_rope_head_dim wide) shared by every head;
+# head h's key is [c W_UK_h, k_r] and its value c W_UV_h.  Attention is
+# computed in the absorbed form throughout the served path:
+#
+#   score_h(t) = (q_nope_h W_UK_h^T) . c_t + q_rope_h . k_r_t
+#   out_h      = (sum_t p_h(t) c_t) W_UV_h
+#
+# so the pool, one [c, k_r] row of kv_lora + rope per token (zero-padded
+# to the lane tiling, 576 -> 640, for the kernel's page DMAs), is read as
+# one 576-wide "head" that 16 query heads share (MQA over the latent; the
+# values are its first kv_lora columns) and is never expanded per head.
+# Params:
+#   wq (d, H*(nope+rope)), wkv_a (d, kv_lora + rope), kv_norm (kv_lora,),
+#   wk_b (H, nope, kv_lora) = W_UK_h^T, wv_b (H, kv_lora, v), wo (H*v, d).
+# The rotary dims use the rotate-half layout of ``apply_rope``; the
+# latent's RMSNorm takes eps 1e-6 (the HF module's default), whatever
+# the model's.
+
+LATENT_EPS = 1e-6
+
+
+def mla_param_shapes(cfg: ModelConfig) -> dict:
+    a, d, h = cfg.mla, cfg.d_model, cfg.n_heads
+    return {
+        "wq": (d, h * a.qk_head_dim),
+        "wkv_a": (d, a.latent_dim),
+        "kv_norm": (a.kv_lora_rank,),
+        "wk_b": (h, a.qk_nope_head_dim, a.kv_lora_rank),
+        "wv_b": (h, a.kv_lora_rank, a.v_head_dim),
+        "wo": (h * a.v_head_dim, d),
+    }
+
+
+MLA_PARAM_AXES = {
+    "wq": ("fsdp", "heads_fused"),
+    "wkv_a": ("fsdp", None),
+    "kv_norm": (None,),
+    "wk_b": ("heads", None, None),
+    "wv_b": ("heads", None, None),
+    "wo": ("heads_fused", "fsdp"),
+}
+
+
+def init_mla(cfg: ModelConfig, key, dtype) -> dict:
+    shapes = mla_param_shapes(cfg)
+    keys = split_keys(key, len(shapes))
+    out = {}
+    for (name, shape), k in zip(sorted(shapes.items()), keys):
+        if name.endswith("_norm"):
+            out[name] = jnp.zeros(shape, dtype)
+        else:
+            out[name] = init_dense(k, shape, dtype=dtype)
+    return out
+
+
+def mla_project(params: dict, x: jax.Array, cfg: ModelConfig, *,
+                positions: jax.Array):
+    """x: (B, S, d) -> absorbed query (q_c (B, H, S, kv_lora), q_r
+    (B, H, S, rope)) and the tokens' cache entries (c (B, S, kv_lora),
+    k_r (B, S, rope)), RoPE applied at ``positions`` (B, S)."""
+    a, h = cfg.mla, cfg.n_heads
+    b, s, _ = x.shape
+    q = x @ params["wq"].astype(x.dtype)
+    q = q.reshape(b, s, h, a.qk_head_dim).transpose(0, 2, 1, 3)
+    q_nope = q[..., :a.qk_nope_head_dim]
+    q_r = apply_rope(q[..., a.qk_nope_head_dim:], positions[:, None, :],
+                     cfg.rope_theta)
+    q_c = jnp.einsum("bhsn,hnc->bhsc", q_nope,
+                     params["wk_b"].astype(x.dtype))
+    kv = x @ params["wkv_a"].astype(x.dtype)
+    c = rms_norm(kv[..., :a.kv_lora_rank], params["kv_norm"], LATENT_EPS)
+    k_r = apply_rope(kv[..., a.kv_lora_rank:], positions, cfg.rope_theta)
+    return q_c, q_r, c, k_r
+
+
+def mla_output(params: dict, ctx_c: jax.Array) -> jax.Array:
+    """ctx_c: (B, H, S, kv_lora) attention-weighted latents -> (B, S, d)."""
+    b, h, s, _ = ctx_c.shape
+    o = jnp.einsum("bhsc,hcv->bshv", ctx_c,
+                   params["wv_b"].astype(ctx_c.dtype))
+    return o.reshape(b, s, -1) @ params["wo"].astype(ctx_c.dtype)
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    return 1.0 / (cfg.mla.qk_head_dim ** 0.5)
+
+
+def _mla_sdpa(q, latent, mask, scale, lora: int):
+    """q: (B, H, Q, lora + rope) absorbed queries; latent: (B, K, lora +
+    rope), each token's [c, k_r]; mask: (B, 1, Q, K) -> (B, H, Q, lora):
+    the scores read the whole latent, the values its first ``lora``."""
+    scores = jnp.einsum("bhqc,bkc->bhqk", q, latent,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(mask, scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhqk,bkc->bhqc", probs.astype(latent.dtype),
+                      latent[..., :lora])
+
+
+def mla_attention(q_c, q_r, c, k_r, *, lens, scale) -> jax.Array:
+    """Causal MLA over a whole sequence (no cache): (B, H, S, kv_lora)."""
+    b, s = c.shape[:2]
+    pos = jnp.broadcast_to(jnp.arange(s), (b, s))
+    m = _mask(pos, pos, lens, causal=True, window=0)
+    q = jnp.concatenate([q_c, q_r], axis=-1)
+    latent = jnp.concatenate([c, k_r], axis=-1)
+    return _mla_sdpa(q, latent, m, scale, c.shape[-1]).astype(q_c.dtype)
+
+
+def update_latent_pages(pages, page_table, c_new, r_new, positions, valid):
+    """Scatter a chunk's latents into the latent pool, laid out as a
+    one-head pool (NP, 1, ps, W) whose rows are [c, k_r, 0...] (W is
+    kv_lora + rope padded to the lane tiling): c_new (B, C, kv_lora),
+    r_new (B, C, rope)."""
+    pad = pages.shape[-1] - c_new.shape[-1] - r_new.shape[-1]
+    latent = jnp.concatenate(
+        [c_new, r_new, jnp.zeros(c_new.shape[:-1] + (pad,), c_new.dtype)],
+        axis=-1)                                    # (B, C, W)
+    phys, off = _page_slots(pages.shape, page_table, positions, valid)
+    return pages.at[phys, 0, off, :].set(latent.astype(pages.dtype),
+                                         mode="drop")
+
+
+def paged_mla_chunk_attention(q_c, q_r, pages, page_table, *, q_pos,
+                              kv_len, chunk_lens, scale) -> jax.Array:
+    """A chunk of queries against the latent pool (gather path).
+
+    q_c: (B, H, C, kv_lora), q_r: (B, H, C, rope); pages (NP, 1, ps,
+    kv_lora + rope); page_table (B, MP); q_pos (B, C); kv_len (B,)
+    tokens including the chunk's.  Rows run one at a time (a row's
+    scores are (H, C, MP*ps)) and a row with no tokens in the chunk is
+    skipped.
+    """
+    from repro.kernels.ref import paged_gather
+
+    lora = q_c.shape[-1]
+    q = jnp.concatenate([q_c, q_r], axis=-1)
+    width = q.shape[-1]
+
+    def row(args):
+        qr, pt, qp, n, live = args
+
+        def attend(_):
+            latent = paged_gather(pages, pt[None])[:, 0, :, :width]
+            kv_pos = jnp.arange(latent.shape[1])[None]
+            m = _mask(qp[None], kv_pos, n[None], causal=True, window=0)
+            return _mla_sdpa(qr[None], latent, m, scale, lora)[0]
+
+        return jax.lax.cond(live > 0, attend,
+                            lambda _: jnp.zeros(qr.shape[:-1] + (lora,),
+                                                qr.dtype), None)
+
+    out = jax.lax.map(row, (q, page_table, q_pos, kv_len, chunk_lens))
+    return out.astype(q_c.dtype)
+
+
+def paged_mla_decode_attention(q_c, q_r, pages, page_table, kv_len, *,
+                               scale) -> jax.Array:
+    """One query token per lane against the latent pool (jnp path):
+    q_c (B, H, kv_lora), q_r (B, H, rope) -> (B, H, kv_lora)."""
+    from repro.kernels.ref import paged_gather
+
+    q = jnp.concatenate([q_c, q_r], axis=-1)[:, :, None]
+    latent = paged_gather(pages, page_table)[:, 0, :, :q.shape[-1]]
+    b, t = latent.shape[:2]
+    kv_pos = jnp.broadcast_to(jnp.arange(t), (b, t))
+    m = _mask((kv_len - 1)[:, None], kv_pos, kv_len, causal=True, window=0)
+    out = _mla_sdpa(q, latent, m, scale, q_c.shape[-1])
+    return out[:, :, 0].astype(q_c.dtype)

@@ -9,12 +9,19 @@ segments (the weight-shared attention block's params are closed over as
 scan constants, which is exactly weight sharing).
 
 Block kinds: dense / moe / mamba / encoder / local / global /
-shared_attn.  One code path serves all ten assigned architectures.
+shared_attn / mla / mla_dense.  One code path serves every architecture.
+
+Each block also returns an ``aux`` vector: the MoE balance loss, then
+the held experts' load (assignments computed, experts hit), summed
+over layers.  ``chunk_step`` and the decode blocks of a model with an
+expert layer return the load as a last output; other models return
+none.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import partial
 from typing import Any, Optional, Sequence
 
@@ -36,7 +43,26 @@ from repro.models.common import (
     unembed,
 )
 
-ATTN_KINDS = ("dense", "moe", "encoder", "local", "global", "shared_attn")
+ATTN_KINDS = ("dense", "moe", "encoder", "local", "global", "shared_attn",
+              "mla", "mla_dense")
+MLA_KINDS = ("mla", "mla_dense")
+MOE_KINDS = ("moe", "mla")
+N_AUX = 3   # balance loss, expert_tokens, experts_hit
+
+
+def _no_aux():
+    return jnp.zeros((N_AUX,), jnp.float32)
+
+
+def _at_precision(fn):
+    """Trace ``fn`` under the model's ``matmul_precision``."""
+    @functools.wraps(fn)
+    def traced(self, *args, **kwargs):
+        if self.cfg.matmul_precision is None:
+            return fn(self, *args, **kwargs)
+        with jax.default_matmul_precision(self.cfg.matmul_precision):
+            return fn(self, *args, **kwargs)
+    return traced
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,11 +103,12 @@ def _init_block(cfg: ModelConfig, key, kind: str, dtype):
         }
     k1, k2, k3, k4, k5 = split_keys(key, 5)
     p = {
-        "attn": attn.init_attn(cfg, k1, dtype),
+        "attn": (attn.init_mla(cfg, k1, dtype) if kind in MLA_KINDS
+                 else attn.init_attn(cfg, k1, dtype)),
         "ln1": jnp.zeros((cfg.d_model,), dtype),
         "ln2": jnp.zeros((cfg.d_model,), dtype),
     }
-    if kind == "moe":
+    if kind in MOE_KINDS:
         p["moe"] = moe.init_moe(cfg, k2, dtype)
     else:
         d, f = cfg.d_model, cfg.d_ff
@@ -104,18 +131,20 @@ def _block_axes(cfg: ModelConfig, kind: str, n_lead: int):
             },
             "ln": lead + (None,),
         }
+    if kind in MLA_KINDS:
+        attn_axes = {k: attn.MLA_PARAM_AXES[k]
+                     for k in attn.mla_param_shapes(cfg)}
+    else:
+        attn_axes = {k: v for k, v in attn.ATTN_PARAM_AXES.items()
+                     if cfg.qkv_bias or not k.startswith("b")}
     out = {
-        "attn": {
-            k: lead + tuple(v)
-            for k, v in attn.ATTN_PARAM_AXES.items()
-            if cfg.qkv_bias or not k.startswith("b")
-        },
+        "attn": {k: lead + tuple(v) for k, v in attn_axes.items()},
         "ln1": lead + (None,),
         "ln2": lead + (None,),
     }
-    if kind == "moe":
+    if kind in MOE_KINDS:
         out["moe"] = {
-            k: lead + tuple(v) for k, v in moe.MOE_PARAM_AXES.items()
+            k: lead + tuple(v) for k, v in moe.moe_param_axes(cfg).items()
         }
     else:
         out["ffn"] = {
@@ -164,11 +193,19 @@ class Model:
 
     def __post_init__(self):
         self.segments = build_segments(self.cfg)
-        self.has_shared = any(
-            s.kind == "shared_attn"
-            or (s.inner and any(k == "shared_attn" for k, _ in s.inner))
-            for s in self.segments
-        )
+        self.has_shared = "shared_attn" in self.block_kinds
+        # the held experts' load rides out of the step programs
+        self.counts_experts = bool(self.block_kinds & set(MOE_KINDS))
+
+    @property
+    def block_kinds(self) -> set:
+        kinds = set()
+        for s in self.segments:
+            if s.kind == "group":
+                kinds.update(k for k, _ in s.inner)
+            else:
+                kinds.add(s.kind)
+        return kinds
 
     # -- init ---------------------------------------------------------------
     def init(self, key) -> dict:
@@ -237,6 +274,12 @@ class Model:
                     make_cache, cache_len, decode, chunked=False,
                     page_table=None):
         cfg = self.cfg
+        if kind in MLA_KINDS:
+            return self._mla_block(
+                bp, x, kind, positions=positions, lens=lens, cache=cache,
+                make_cache=make_cache, decode=decode, chunked=chunked,
+                page_table=page_table,
+            )
         window = cfg.window if kind == "local" else 0
         causal = cfg.causal
         use_rope = cfg.frontend != "frames"
@@ -292,11 +335,21 @@ class Model:
         ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, -1)
         o = ctx @ bp["attn"]["wo"].astype(x.dtype)
         x = x + o
+        valid = (jnp.arange(x.shape[1])[None, :] < lens[:, None]
+                 if chunked else None)
+        x, aux = self._ffn_block(bp, x, kind, valid)
+        return x, new_cache, aux
+
+    def _ffn_block(self, bp, x, kind, valid):
+        """The block's second half: x -> (x + FFN(norm(x)), aux)."""
+        cfg = self.cfg
         h = rms_norm(x, bp["ln2"], cfg.norm_eps)
-        aux = jnp.zeros((), jnp.float32)
-        if kind == "moe":
-            y, moe_aux = moe.moe_ffn(bp["moe"], h, cfg)
-            aux = moe_aux["lb_loss"]
+        aux = _no_aux()
+        if kind in MOE_KINDS:
+            y, a = moe.moe_ffn(bp["moe"], h, cfg, valid=valid)
+            aux = jnp.stack([a["lb_loss"],
+                             a["expert_tokens"].astype(jnp.float32),
+                             a["experts_hit"].astype(jnp.float32)])
         else:
             f = bp["ffn"]
             y = swiglu(h, f["w_gate"].astype(x.dtype),
@@ -304,7 +357,47 @@ class Model:
                        f["w_down"].astype(x.dtype))
         y = constrain(y, "batch", "seq", "embed")
         out = constrain(x + y, "batch", "seq", "residual")
-        return out, new_cache, aux
+        return out, aux
+
+    def _mla_block(self, bp, x, kind, *, positions, lens, cache,
+                   make_cache, decode, chunked, page_table):
+        """Latent attention (absorbed form, see ``attention.py``), then
+        the dense or expert FFN.  Runs on the paged plane (and whole
+        sequences without a cache); it keeps no slot-plane cache."""
+        cfg = self.cfg
+        h = rms_norm(x, bp["ln1"], cfg.norm_eps)
+        q_c, q_r, c, k_r = attn.mla_project(bp["attn"], h, cfg,
+                                            positions=positions)
+        scale = attn.mla_scale(cfg)
+        new_cache, valid = None, None
+        if chunked:
+            s = x.shape[1]
+            valid = jnp.arange(s)[None, :] < lens[:, None]
+            pages = attn.update_latent_pages(
+                cache["latent_pages"], page_table, c, k_r, positions, valid)
+            kv_len = positions[:, 0] + lens
+            if s > 1:
+                ctx = attn.paged_mla_chunk_attention(
+                    q_c, q_r, pages, page_table, q_pos=positions,
+                    kv_len=kv_len, chunk_lens=lens, scale=scale,
+                )
+            else:
+                # the one kernel decision (see repro.kernels.ops)
+                fn = (ops.paged_mla_decode_attention if ops.kernels_enabled()
+                      else attn.paged_mla_decode_attention)
+                ctx = fn(q_c[:, :, 0], q_r[:, :, 0], pages, page_table,
+                         kv_len, scale=scale)[:, :, None]
+            new_cache = {"latent_pages": pages}
+        elif decode or make_cache:
+            raise NotImplementedError(
+                "latent attention keeps its cache in the paged plane "
+                "only; serve it through chunk_step")
+        else:
+            ctx = attn.mla_attention(q_c, q_r, c, k_r, lens=lens,
+                                     scale=scale)
+        x, aux = self._ffn_block(bp, x + attn.mla_output(bp["attn"], ctx),
+                                 kind, valid)
+        return x, new_cache, aux
 
     def _build_cache(self, k, v, lens, window, cache_len):
         if window > 0:
@@ -337,7 +430,7 @@ class Model:
         if make_cache or decode:
             new_cache = {"conv": new_conv, "ssm": new_ssm}
         out = constrain(x + y, "batch", "seq", "residual")
-        return out, new_cache, jnp.zeros((), jnp.float32)
+        return out, new_cache, _no_aux()
 
     def _block(self, kind, bp, shared, x, *, positions, lens, cache,
                make_cache, cache_len, decode, chunked=False,
@@ -391,7 +484,7 @@ class Model:
                 xs_i = jax.tree.map(lambda a: a[i], xs)
                 x, outs_i = layer(x, xs_i)
                 outs_list.append(outs_i)
-            aux = jnp.sum(jnp.stack([o[0] for o in outs_list]))
+            aux = jnp.sum(jnp.stack([o[0] for o in outs_list]), axis=0)
             if len(outs_list[0]) > 1:
                 new_cache = jax.tree.map(
                     lambda *leaves: jnp.stack(leaves),
@@ -401,7 +494,7 @@ class Model:
                 new_cache = None
             return x, new_cache, aux
         x, outs = jax.lax.scan(layer, x, xs)
-        aux = jnp.sum(outs[0])
+        aux = jnp.sum(outs[0], axis=0)
         new_cache = outs[1] if len(outs) > 1 else None
         return x, new_cache, aux
 
@@ -441,7 +534,7 @@ class Model:
                         else jax.tree.map(lambda a: a[i], cache))
                 x, outs_i = group_body(x, (gp_i, gc_i))
                 outs_list.append(outs_i)
-            aux = jnp.sum(jnp.stack([o[0] for o in outs_list]))
+            aux = jnp.sum(jnp.stack([o[0] for o in outs_list]), axis=0)
             if len(outs_list[0]) > 1:
                 new_cache = jax.tree.map(
                     lambda *leaves: jnp.stack(leaves),
@@ -456,7 +549,7 @@ class Model:
             x, outs = jax.lax.scan(body_nc, x, seg_params)
         else:
             x, outs = jax.lax.scan(group_body, x, (seg_params, cache))
-        aux = jnp.sum(outs[0])
+        aux = jnp.sum(outs[0], axis=0)
         new_cache = outs[1] if len(outs) > 1 else None
         return x, new_cache, aux
 
@@ -465,7 +558,7 @@ class Model:
                       page_table=None):
         shared = params.get("shared")
         new_caches = []
-        aux_total = jnp.zeros((), jnp.float32)
+        aux_total = _no_aux()
         for i, spec in enumerate(self.segments):
             seg_p = params["segments"][i]
             seg_c = caches[i] if caches is not None else None
@@ -490,6 +583,7 @@ class Model:
             x = embed(batch["tokens"], params["embed"], self.compute_dtype)
         return x
 
+    @_at_precision
     def forward(self, params, batch, return_aux: bool = False):
         """Full-sequence forward -> logits (B, S, V)."""
         cfg = self.cfg
@@ -522,8 +616,9 @@ class Model:
             ce = jnp.sum(tok_loss * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         else:
             ce = jnp.mean(tok_loss)
-        return ce + 0.01 * aux
+        return ce + 0.01 * aux[0]
 
+    @_at_precision
     def prefill(self, params, tokens_or_frames, lens, *,
                 cache_len: Optional[int] = None):
         """Process prompts, return (last-token logits (B, V), caches)."""
@@ -555,13 +650,8 @@ class Model:
         frame-frontend models (never served incrementally)."""
         if self.cfg.is_encoder_only or self.cfg.frontend == "frames":
             return False
-        kinds = set()
-        for s in self.segments:
-            if s.kind == "group":
-                kinds.update(k for k, _ in s.inner)
-            else:
-                kinds.add(s.kind)
-        return kinds <= {"dense", "moe", "mamba", "global", "shared_attn"}
+        return self.block_kinds <= {"dense", "moe", "mamba", "global",
+                                    "shared_attn", "mla", "mla_dense"}
 
     @property
     def supports_prefix_cache(self) -> bool:
@@ -572,13 +662,7 @@ class Model:
         have produced — prefix caching must refuse such models."""
         if not self.supports_chunked:
             return False
-        kinds = set()
-        for s in self.segments:
-            if s.kind == "group":
-                kinds.update(k for k, _ in s.inner)
-            else:
-                kinds.add(s.kind)
-        return "mamba" not in kinds
+        return "mamba" not in self.block_kinds
 
     @property
     def supports_spec_decode(self) -> bool:
@@ -589,6 +673,7 @@ class Model:
         refused for them — mirroring ``supports_prefix_cache``."""
         return self.supports_prefix_cache
 
+    @_at_precision
     def chunk_step(self, params, caches, page_table, tokens, start,
                    chunk_lens):
         """Unified chunked-prefill / decode step over *paged* caches.
@@ -605,7 +690,7 @@ class Model:
         x = embed(tokens, params["embed"], self.compute_dtype)
         b, c = tokens.shape
         positions = start[:, None] + jnp.arange(c)[None, :]
-        x, new_caches, _ = self._run_segments(
+        x, new_caches, aux = self._run_segments(
             params, x, positions=positions, lens=chunk_lens, caches=caches,
             make_cache=True, cache_len=0, decode=False, chunked=True,
             page_table=page_table,
@@ -615,7 +700,14 @@ class Model:
         x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
         table = params["embed"] if cfg.tie_embeddings else params["head"]
         logits = x_last @ table.T.astype(x_last.dtype)
-        return logits, new_caches
+        return (logits, new_caches) + self._expert_load(aux)
+
+    def _expert_load(self, aux) -> tuple:
+        """``(expert_tokens, experts_hit)`` int32 as a one-item tuple
+        for a model with an expert layer, else ()."""
+        if not self.counts_experts:
+            return ()
+        return (aux[1:].astype(jnp.int32),)
 
     # -- fused decode blocks ---------------------------------------------------
     #
@@ -653,26 +745,30 @@ class Model:
         rows — idle or mid-prefill slots — are frozen: zero chunk
         length drops their writes); eos: scalar int32 (-1 disables);
         max_len: scalar int32; ``k`` is static (jit per block size).
-        Returns ``(tokens (B, K), valid (B, K), last, pos), caches`` —
+        Returns ``(tokens (B, K), valid (B, K), last, pos), caches``
+        (a model with an expert layer adds its load summed over the
+        block after ``pos``) —
         ``valid[b, i]`` marks lanes that really emitted a token, so a
         row stopping mid-block yields a partially-consumed block.
         """
         def body(carry, _):
             caches, last, pos, alive, rem = carry
-            logits, caches = self.chunk_step(
+            logits, caches, *load = self.chunk_step(
                 params, caches, page_table, last[:, None], pos,
                 alive.astype(jnp.int32),
             )
             tok, new_pos, new_alive, new_rem = self._decode_block_body(
                 last, pos, alive, rem, eos, max_len, logits,
             )
-            return (caches, tok, new_pos, new_alive, new_rem), (tok, alive)
+            return ((caches, tok, new_pos, new_alive, new_rem),
+                    (tok, alive, *load))
 
         init = (caches, last, pos, alive, rem)
-        (caches, last, pos, alive, rem), (toks, valid) = jax.lax.scan(
-            body, init, None, length=k
-        )
-        return (toks.T, valid.T, last, pos), caches
+        (caches, last, pos, alive, rem), (toks, valid, *load) = (
+            jax.lax.scan(body, init, None, length=k))
+        # the held experts' load, summed over the block's iterations
+        load = tuple(jnp.sum(a, axis=0) for a in load)
+        return (toks.T, valid.T, last, pos) + load, caches
 
     def decode_block_slots(self, params, caches, last, pos, alive, rem,
                            eos, max_len, *, k: int):
@@ -700,6 +796,7 @@ class Model:
         )
         return (toks.T, valid.T, last, pos), caches
 
+    @_at_precision
     def spec_decode_block(self, params, caches, page_table, last, pos,
                           alive, rem, eos, max_len, props, prop_lens,
                           *, k: int):
@@ -728,7 +825,7 @@ class Model:
         chunk_lens = jnp.where(alive, 1 + prop_lens, 0).astype(jnp.int32)
         x = embed(tokens, params["embed"], self.compute_dtype)
         positions = pos[:, None] + jnp.arange(k + 1)[None, :]
-        x, new_caches, _ = self._run_segments(
+        x, new_caches, aux = self._run_segments(
             params, x, positions=positions, lens=chunk_lens, caches=caches,
             make_cache=True, cache_len=0, decode=False, chunked=True,
             page_table=page_table,
@@ -763,8 +860,10 @@ class Model:
         pick = jnp.clip(emitted - 1, 0, k)
         last_tok = jnp.take_along_axis(t, pick[:, None], axis=1)[:, 0]
         new_last = jnp.where(emitted > 0, last_tok, last)
-        return (t, valid, new_last, new_pos), new_caches
+        return ((t, valid, new_last, new_pos) + self._expert_load(aux),
+                new_caches)
 
+    @_at_precision
     def decode_step(self, params, caches, tokens, pos):
         """tokens: (B,) int32 last sampled; pos: (B,) their positions.
 
@@ -788,6 +887,9 @@ class Model:
     def init_cache(self, batch_size: int, max_len: int):
         """Zero caches with static shapes (dtype = compute_dtype)."""
         cfg = self.cfg
+        if self.block_kinds & set(MLA_KINDS):
+            raise NotImplementedError(
+                "latent attention keeps its cache in the paged plane only")
         hd = cfg.resolved_head_dim
         hkv = cfg.n_kv_heads * self.kv_repeat
 
@@ -855,9 +957,15 @@ class Model:
         if n_pages is None:
             n_pages = n_slots * (-(-max_len // page_size))
 
-        def paged_attn(n_lead):
-            shape = (n_pages, hkv, page_size, hd)
+        def paged_attn(n_lead, kind):
             lead = tuple(n_lead)
+            if kind in MLA_KINDS:
+                # one [latent, rotary key] row per token, laid out as a
+                # one-head pool
+                shape = (n_pages, 1, page_size, cfg.mla.pool_width)
+                return {"latent_pages": jnp.zeros(lead + shape,
+                                                  self.compute_dtype)}
+            shape = (n_pages, hkv, page_size, hd)
             return {
                 "k_pages": jnp.zeros(lead + shape, self.compute_dtype),
                 "v_pages": jnp.zeros(lead + shape, self.compute_dtype),
@@ -874,8 +982,8 @@ class Model:
             if spec.kind == "mamba":
                 return self._mamba_cache(n_slots, lead + (spec.count,))
             if spec.kind == "shared_attn":
-                return paged_attn(lead)
-            return paged_attn(lead + (spec.count,))
+                return paged_attn(lead, "dense")
+            return paged_attn(lead + (spec.count,), spec.kind)
 
         return [seg_cache(s) for s in self.segments]
 
@@ -893,6 +1001,8 @@ class Model:
             if spec.kind == "mamba":
                 b = len(lead) + 1
                 return {"conv": {"x": b, "bc": b}, "ssm": b}
+            if spec.kind in MLA_KINDS:
+                return {"latent_pages": None}
             return {"k_pages": None, "v_pages": None}
 
         return [seg_axes(s) for s in self.segments]

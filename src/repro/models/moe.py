@@ -1,18 +1,32 @@
-"""Top-k mixture-of-experts FFN with sort-based token dispatch.
+"""Dropless top-k mixture-of-experts FFN over the experts this chip holds.
 
-Dense one-hot dispatch (Mesh-TensorFlow style) materializes an
-O(T * E * C) tensor — intractable at the train_4k cell (1M tokens,
-64 experts).  We instead use the production (MaxText/vLLM-style)
-sort-based formulation: flatten the (token, k) assignments, stable-sort
-by expert id, compute the position-within-expert by subtracting each
-run's start index, scatter into a fixed-capacity (E, C, d) buffer
-(overflow tokens drop, like the paper's capacity-factor routers), run
-the experts as one batched matmul, and gather/combine back.
+The router scores every expert of the model (``MoEConfig.num_experts``)
+and picks ``top_k`` per token.  The chip holds ``n_held`` of them, from
+``held_offset`` on, and computes every assignment to a held expert:
+there is no capacity, so a token's result never depends on what else is
+in the batch.  Assignments to experts held elsewhere are left out; under
+expert parallelism their part of the sum arrives from the chips that
+hold them (on one chip the layer runs without that exchange).
 
-Expert weights carry the 'experts' logical axis (→ model axis on the
-production mesh): the scatter/gather across the data→expert sharding
-boundary is exactly the all-to-all of classic expert parallelism, and is
-inserted by the SPMD partitioner.
+Dispatch sorts the flat (token, k) assignment list by held expert, the
+assignments to absent experts (and padding tokens) last, and runs the
+experts' SwiGLU as three ``jax.lax.ragged_dot`` over the sorted rows,
+one group per held expert; rows past the held groups are zeroed.
+The combine scatters each row, times its routing weight, back onto its
+token.  Shared experts (DeepSeek-V3) run for every token and are added
+without a gate.
+
+Two routers:
+
+- ``softmax``: the k largest softmax probabilities are the weights;
+- ``sigmoid`` (DeepSeek-V3's ``noaux_tc``): scores are
+  ``sigmoid(logits)``; experts are *selected* on ``scores + bias`` and
+  *weighted* by their unbiased scores.
+
+Either renormalizes the k weights to sum to 1 and scales them by
+``routed_scaling``.  The router's logits are float32 products at
+``HIGHEST`` precision: one bf16 pass flips near-tied top-k choices, and
+a flipped choice moves a served token's logits far.
 """
 
 from __future__ import annotations
@@ -21,128 +35,122 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import constrain
-from repro.models.common import init_dense, silu, split_keys
+from repro.models.common import init_dense, silu, split_keys, swiglu
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def moe_param_shapes(cfg: ModelConfig) -> dict:
     m = cfg.moe
-    d = cfg.d_model
-    return {
+    d, e, f = cfg.d_model, m.held, m.expert_d_ff
+    shapes = {
         "w_router": (d, m.num_experts),
-        "w_gate": (m.num_experts, d, m.expert_d_ff),
-        "w_up": (m.num_experts, d, m.expert_d_ff),
-        "w_down": (m.num_experts, m.expert_d_ff, d),
+        "w_gate": (e, d, f),
+        "w_up": (e, d, f),
+        "w_down": (e, f, d),
     }
+    if m.router == "sigmoid":
+        shapes["router_bias"] = (m.num_experts,)
+    if m.n_shared:
+        fs = m.n_shared * f
+        shapes.update(ws_gate=(d, fs), ws_up=(d, fs), ws_down=(fs, d))
+    return shapes
 
 
 MOE_PARAM_AXES = {
     "w_router": ("fsdp", None),
+    "router_bias": (None,),
     "w_gate": ("experts", "fsdp", "ff"),
     "w_up": ("experts", "fsdp", "ff"),
     "w_down": ("experts", "ff", "fsdp"),
+    "ws_gate": ("fsdp", "ff"),
+    "ws_up": ("fsdp", "ff"),
+    "ws_down": ("ff", "fsdp"),
 }
+
+
+def moe_param_axes(cfg: ModelConfig) -> dict:
+    return {k: MOE_PARAM_AXES[k] for k in moe_param_shapes(cfg)}
 
 
 def init_moe(cfg: ModelConfig, key, dtype) -> dict:
     shapes = moe_param_shapes(cfg)
     keys = split_keys(key, len(shapes))
-    return {
-        name: init_dense(k, shape, dtype=dtype)
-        for (name, shape), k in zip(sorted(shapes.items()), keys)
-    }
+    out = {}
+    for (name, shape), k in zip(sorted(shapes.items()), keys):
+        if name == "router_bias":
+            out[name] = jnp.zeros(shape, dtype)
+        else:
+            out[name] = init_dense(k, shape, dtype=dtype)
+    return out
 
 
-def capacity(cfg: ModelConfig, n_tokens: int) -> int:
+def route(params: dict, xt: jax.Array, cfg: ModelConfig):
+    """xt: (T, d) -> (probs (T, E) for the balance loss, chosen experts
+    (T, k) int32, their weights (T, k) float32)."""
     m = cfg.moe
-    c = int(n_tokens * m.top_k * m.capacity_factor / m.num_experts)
-    return max(8, -(-c // 8) * 8)  # round up to a multiple of 8
+    logits = jnp.matmul(xt.astype(jnp.float32),
+                        params["w_router"].astype(jnp.float32),
+                        precision=HIGHEST)
+    if m.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, sel = jax.lax.top_k(
+            scores + params["router_bias"].astype(jnp.float32), m.top_k)
+        weight = jnp.take_along_axis(scores, sel, axis=-1)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        weight, sel = jax.lax.top_k(probs, m.top_k)
+    weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+    return probs, sel, weight * m.routed_scaling
 
 
-def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig):
-    """x: (B, S, d) -> (y: (B, S, d), aux: dict with load-balance loss)."""
+def _ragged(x, w, sizes):
+    return jax.lax.ragged_dot(x, w.astype(x.dtype), sizes)
+
+
+def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig, valid=None):
+    """x: (B, S, d); valid: optional (B, S) bool, False for padding
+    tokens (routed nowhere, so they take no expert's time).
+
+    Returns (y (B, S, d), aux) with aux holding the balance loss
+    ``lb_loss`` and the load of the held experts: ``expert_tokens``
+    (assignments computed) and ``experts_hit`` (held experts with at
+    least one), both int32 scalars."""
     m = cfg.moe
     b, s, d = x.shape
-    t = b * s
+    t, k, held = b * s, m.top_k, m.held
     xt = x.reshape(t, d)
-    e, k = m.num_experts, m.top_k
-    g = m.dispatch_groups if (m.dispatch_groups and
-                              t % m.dispatch_groups == 0) else 1
-    tg = t // g
-    cap = capacity(cfg, tg)
-    xg = constrain(xt.reshape(g, tg, d), "moe_group", None, "embed")
+    probs, sel, weight = route(params, xt, cfg)
 
-    router_logits = (xg @ params["w_router"].astype(xt.dtype)).astype(
-        jnp.float32
-    )
-    probs = jax.nn.softmax(router_logits, axis=-1)  # (G, Tg, E)
-    gate, sel = jax.lax.top_k(probs, k)  # (G, Tg, k)
-    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    local = sel - m.held_offset
+    mine = (local >= 0) & (local < held)
+    if valid is not None:
+        mine = mine & valid.reshape(t, 1)
+    key = jnp.where(mine, local, held).reshape(t * k)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(key, held + 1, dtype=jnp.int32),
+                    axis=0)[:held]
+    n_rows = jnp.sum(sizes)
+    tok = order // k
+    rows = xt[tok]                                  # (T*k, d), by expert
+    h = silu(_ragged(rows, params["w_gate"], sizes)) * _ragged(
+        rows, params["w_up"], sizes)
+    out = _ragged(h, params["w_down"], sizes)
+    live = jnp.arange(t * k) < n_rows
+    wk = jnp.where(mine, weight, 0.0).reshape(t * k)[order]
+    out = jnp.where(live[:, None], out, 0.0) * wk[:, None].astype(out.dtype)
+    y = jnp.zeros((t, d), x.dtype).at[tok].add(out.astype(x.dtype))
 
-    # --- sort-based dispatch, vectorized over groups -----------------------
-    # pin every routing tensor to group(=data) sharding so the SPMD
-    # partitioner never reshards the sort/gather pipeline
-    def pin(t):
-        if g == 1:
-            return t
-        return constrain(t, "moe_group", None)
+    if m.n_shared:
+        y = y + swiglu(xt, *(params[n].astype(x.dtype)
+                             for n in ("ws_gate", "ws_up", "ws_down")))
 
-    flat_e = pin(sel.reshape(g, tg * k))
-    order = pin(jnp.argsort(flat_e, axis=-1, stable=True))
-    sorted_e = pin(jnp.take_along_axis(flat_e, order, axis=-1))
-    # position within each expert's run of the sorted assignment list
-    run_start = pin(jax.vmap(
-        lambda a: jnp.searchsorted(a, a, side="left")
-    )(sorted_e))
-    pos_in_e = jnp.arange(tg * k)[None, :] - run_start
-    keep = pos_in_e < cap
-    # overflow assignments get an out-of-bounds position (mode="drop")
-    pos_c = pin(jnp.where(keep, pos_in_e, cap))
-    tok = pin(order // k)
-
-    # (G, Tg*k, d) gather; rows are expert-sorted within each group, so
-    # the dispatch stays group-local (groups align with data shards)
-    src = jnp.take_along_axis(xg, tok[..., None], axis=1)
-    if g == 1:
-        src = constrain(src, None, "experts", "embed")
-    gi = jnp.arange(g)[:, None]
-    # 3-index scatter straight into the (G, E, C, d) buffer whose target
-    # sharding is pinned on the zeros operand — the scatter then executes
-    # sharded instead of materializing a replicated flat buffer.
-    zeros4 = constrain(
-        jnp.zeros((g, e, cap, d), xt.dtype),
-        "moe_group", "experts", None, "embed",
-    )
-    h = zeros4.at[gi, sorted_e, pos_c].set(src, mode="drop")
-    h = constrain(h, "moe_group", "experts", None, "embed")
-
-    # --- expert swiglu ------------------------------------------------------
-    wg = params["w_gate"].astype(h.dtype)
-    wu = params["w_up"].astype(h.dtype)
-    wd = params["w_down"].astype(h.dtype)
-    act = silu(jnp.einsum("gecd,edf->gecf", h, wg))
-    act = act * jnp.einsum("gecd,edf->gecf", h, wu)
-    act = constrain(act, "moe_group", "experts", None, "ff")
-    out = jnp.einsum("gecf,efd->gecd", act, wd)
-    out = constrain(out, "moe_group", "experts", None, "embed")
-
-    # --- combine ------------------------------------------------------------
-    contrib = out.at[gi, sorted_e, pos_c].get(mode="fill", fill_value=0)
-    gate_sorted = jnp.take_along_axis(
-        gate.reshape(g, tg * k), order, axis=-1
-    ).astype(xt.dtype)
-    y = jnp.zeros((g, tg, d), xt.dtype).at[gi, tok].add(
-        contrib * gate_sorted[..., None]
-    )
-    y = constrain(y, "moe_group", None, "embed")
-
-    # --- aux: switch-style load-balance loss + stats ------------------------
-    probs_f = probs.reshape(t, e)
-    me = jnp.mean(probs_f, axis=0)  # mean router prob per expert
-    ce = jnp.mean(
-        jax.nn.one_hot(sel.reshape(t, k), e).sum(axis=1), axis=0
-    )  # fraction routed
-    lb_loss = e * jnp.sum(me * ce) / k
-    dropped = jnp.sum(~keep) / (t * k)
-    aux = {"lb_loss": lb_loss, "drop_frac": dropped}
+    # switch-style balance loss over the router's full width
+    e = m.num_experts
+    me = jnp.mean(probs, axis=0)
+    ce = jnp.mean(jax.nn.one_hot(sel, e).sum(axis=1), axis=0)
+    aux = {"lb_loss": e * jnp.sum(me * ce) / k,
+           "expert_tokens": n_rows.astype(jnp.int32),
+           "experts_hit": jnp.sum(sizes > 0).astype(jnp.int32)}
     return y.reshape(b, s, d), aux

@@ -355,7 +355,8 @@ class InferenceEngine:
     def step(self) -> dict:
         """Run one prefill (chunk) or decode step; returns event info
         with the step's id.  Spans: ``hfx.engine.step`` (its ``kind``,
-        ``k``, ``tokens`` out) holds ``plan`` up to the dispatch, then
+        ``k``, ``tokens`` out, and for an expert model ``expert_tokens``
+        and ``experts_hit``) holds ``plan`` up to the dispatch, then
         ``commit`` (``_dispatch``)."""
         self._step_id = next(_STEP_IDS)
         with tracing.span("hfx.engine.step",
@@ -390,6 +391,17 @@ class InferenceEngine:
         self._phase = tracing.span("hfx.engine.commit")
         self._phase.__enter__()
         return out, dt
+
+    def _annotate_load(self, load) -> None:
+        """The step's held-expert load, for a model with an expert layer
+        (``load`` = its one (expert_tokens, experts_hit) output; empty
+        for others): assignments computed on held experts and held
+        experts with at least one, summed over layers and iterations.
+        Read only while a profiler runs, after the step's sync."""
+        if load:
+            tracing.annotate(self._span,
+                             expert_tokens=lambda: int(load[0][0]),
+                             experts_hit=lambda: int(load[0][1]))
 
     def _step_slots(self) -> dict:
         admitted = self._admit()
@@ -497,7 +509,7 @@ class InferenceEngine:
             start[s] = r.prefill_progress
             lens[s] = t
 
-        (logits, self.caches), dt = self._dispatch(
+        (logits, self.caches, *load), dt = self._dispatch(
             lambda: self._chunk(
                 self.params, self.caches, self.kv.device_table(),
                 jnp.asarray(tokens), jnp.asarray(start), jnp.asarray(lens),
@@ -507,6 +519,7 @@ class InferenceEngine:
                 r for s, r in self.prefilling.items()
                 if takes[s] and r.prefill_progress == r.prefix_hit_tokens),
         )
+        self._annotate_load(load)
         chunk_lens = [t for t in takes.values() if t > 0]
         self.profiler.observe_prefill(chunk_lens, dt)
         self.n_prefill_tokens += int(sum(chunk_lens))
@@ -955,7 +968,8 @@ class InferenceEngine:
         speculative verify (whose ``on_lane(slot, req, emitted)`` runs
         before each slot's tokens land): keep its final state on the
         device, append each slot's valid lanes, feed the fit, retire."""
-        toks, valid, last_f, pos_f = out
+        toks, valid, last_f, pos_f, *load = out
+        self._annotate_load(load)
         # the scan's final state IS the next block's input — resident
         self._dev_state = (last_f, pos_f)
         self._host_state_dirty = False
@@ -1127,7 +1141,8 @@ class InferenceEngine:
         state, account telemetry, and retire — one place to keep the
         paged/slot paths in sync."""
         n_tok = len(self.active)
-        (logits, self.caches), dt = self._dispatch(call)
+        (logits, self.caches, *load), dt = self._dispatch(call)
+        self._annotate_load(load)
         self.profiler.observe_decode(
             [int(self.pos[s]) for s in self.active], dt)
         nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)

@@ -3,8 +3,10 @@
 The TPU compiler is installed here and compiles for a described (not
 attached) ``v5e:2x2`` topology.  These tests compile the served path's
 kernels and programs at ``qwen7b``'s published widths and the shapes
-``chip_smoke.py`` serves, so tiling and VMEM refusals and programs too
-large for the chip surface without chip time.  Only shapes are passed:
+``chip_smoke.py`` serves, and Moonlight-16B-A3B's as the benchmark's
+configuration cuts it (``perfbench/configs/moonlight-16b-a3b.json``),
+so tiling and VMEM refusals and programs too large for the chip surface
+without chip time.  Only shapes are passed:
 nothing is materialized.
 
 The topology is described inside a module fixture, never at import: the
@@ -12,6 +14,8 @@ TPU library may be loaded by one process at a time, and xdist workers
 import every test file.
 """
 
+import importlib.util
+import json
 import os
 import pathlib
 import sys
@@ -22,7 +26,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
-from repro.kernels.decode_attention import paged_decode_attention
+from repro.kernels.decode_attention import (
+    paged_decode_attention,
+    paged_mla_decode_attention,
+)
 from repro.kernels.page_gather import page_gather
 from repro.models import build_model
 
@@ -161,3 +168,86 @@ def test_reference_scorer_fits_one_chip(served_model, one_chip):
         _spec((), jnp.int32, one_chip),
     ).compile()
     _assert_fits(c)
+
+
+# -- Moonlight-16B-A3B, as the benchmark serves it ---------------------------
+
+BENCH = pathlib.Path(__file__).resolve().parents[1] / "perfbench"
+MOONLIGHT = json.loads((BENCH / "configs" / "moonlight-16b-a3b.json")
+                       .read_text())
+ML_ENG = MOONLIGHT["engine"]
+ML_B, ML_MP = ML_ENG["n_slots"], ML_ENG["max_len"] // ML_ENG["page_size"]
+
+
+def _moonlight_config():
+    spec = importlib.util.spec_from_file_location(
+        "deepseek_v3_adapter", BENCH / "adapters" / "deepseek_v3.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.model_config(MOONLIGHT)
+
+
+@pytest.fixture(scope="module")
+def moonlight(one_chip):
+    """The served cut (1 dense + 10 expert layers of 8 held experts, 32
+    lanes of 2048 tokens) in float32, as shapes on one chip: the record
+    behind the depth and the lanes.  One more expert layer takes the
+    prefill chunk program to 15.85 GB; 48 lanes at 8 expert layers, to
+    16.48 GB."""
+    model = build_model(_moonlight_config())
+    params = _on(model.abstract_params(), one_chip)
+    caches = _on(jax.eval_shape(lambda: model.init_paged_cache(
+        ML_B, ML_ENG["max_len"], ML_ENG["page_size"])), one_chip)
+    return model, params, caches
+
+
+def test_mla_decode_kernel_compiles(one_chip):
+    a = _moonlight_config().mla
+
+    def call(q_c, q_r, pages, table, lens):
+        return paged_mla_decode_attention(q_c, q_r, pages, table, lens,
+                                          scale=1.0 / a.qk_head_dim ** 0.5)
+
+    c = jax.jit(call).lower(
+        _spec((ML_B, MOONLIGHT["num_attention_heads"], a.kv_lora_rank),
+              jnp.float32, one_chip),
+        _spec((ML_B, MOONLIGHT["num_attention_heads"], a.qk_rope_head_dim),
+              jnp.float32, one_chip),
+        _spec((ML_B * ML_MP, 1, ML_ENG["page_size"], a.pool_width),
+              jnp.float32, one_chip),
+        _spec((ML_B, ML_MP), jnp.int32, one_chip),
+        _spec((ML_B,), jnp.int32, one_chip),
+    ).compile()
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("chunk", [1, ML_ENG["chunk_size"]])
+def test_moonlight_chunk_step_fits_one_chip(moonlight, one_chip,
+                                            tpu_decision, chunk):
+    model, params, caches = moonlight
+    i32 = jnp.int32
+    c = jax.jit(model.chunk_step).lower(
+        params, caches, _spec((ML_B, ML_MP), i32, one_chip),
+        _spec((ML_B, chunk), i32, one_chip), _spec((ML_B,), i32, one_chip),
+        _spec((ML_B,), i32, one_chip),
+    ).compile()
+    _assert_fits(c)
+    # one-token decode runs the MLA kernel; prefill chunks run jnp (both
+    # run the experts as ragged dots, custom calls of their own)
+    assert ("paged_mla_decode_attention" in c.as_text()) == (chunk == 1)
+    assert "ragged-dot" in c.as_text()
+
+
+def test_moonlight_decode_block_fits_one_chip(moonlight, one_chip,
+                                              tpu_decision):
+    model, params, caches = moonlight
+    i32, vec = jnp.int32, (ML_B,)
+    c = jax.jit(lambda *a: model.decode_block(
+        *a, k=ML_ENG["decode_block"])).lower(
+        params, caches, _spec((ML_B, ML_MP), i32, one_chip),
+        _spec(vec, i32, one_chip), _spec(vec, i32, one_chip),
+        _spec(vec, jnp.bool_, one_chip), _spec(vec, i32, one_chip),
+        _spec((), i32, one_chip), _spec((), i32, one_chip),
+    ).compile()
+    _assert_fits(c)
+    assert "paged_mla_decode_attention" in c.as_text()

@@ -2,8 +2,6 @@
 KV caches, local-window ring buffers, SSD state carry, and shared-block
 caches for every architecture family."""
 
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import pytest
@@ -12,20 +10,13 @@ from repro.configs import get_smoke_config
 from repro.models import build_model
 
 FAMS = ["qwen2.5-14b", "gemma3-4b", "mamba2-2.7b", "zamba2-7b",
-        "olmoe-1b-7b", "chameleon-34b", "command-r-plus-104b"]
-
-
-def _nodrop(cfg):
-    if cfg.moe is not None:
-        return dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0)
-        )
-    return cfg
+        "olmoe-1b-7b", "phi3.5-moe-42b-a6.6b", "chameleon-34b",
+        "command-r-plus-104b"]
 
 
 @pytest.mark.parametrize("name", FAMS)
 def test_decode_matches_forward(name):
-    cfg = _nodrop(get_smoke_config(name))
+    cfg = get_smoke_config(name)
     model = build_model(cfg)
     params = model.init(jax.random.key(1))
     b, s, t = 2, 20, 6
@@ -43,7 +34,8 @@ def test_decode_matches_forward(name):
     assert max(errs) < 1e-4, errs
 
 
-CHUNK_FAMS = ["qwen2.5-14b", "mamba2-2.7b", "zamba2-7b", "olmoe-1b-7b"]
+CHUNK_FAMS = ["qwen2.5-14b", "mamba2-2.7b", "zamba2-7b", "olmoe-1b-7b",
+              "phi3.5-moe-42b-a6.6b"]
 
 
 @pytest.mark.parametrize("name", CHUNK_FAMS)
@@ -53,7 +45,7 @@ def test_chunked_prefill_matches_monolithic_logits(name, chunk):
     reproduce monolithic prefill's last-token logits, then decode
     identically, for every chunk-capable family (dense / SSM / grouped
     shared-attn / MoE)."""
-    cfg = _nodrop(get_smoke_config(name))
+    cfg = get_smoke_config(name)
     model = build_model(cfg)
     assert model.supports_chunked
     params = model.init(jax.random.key(1))
@@ -73,7 +65,7 @@ def test_chunked_prefill_matches_monolithic_logits(name, chunk):
         c = min(chunk, s - start)
         tk = jnp.zeros((b, chunk), jnp.int32)
         tk = tk.at[:, :c].set(toks[:, start: start + c])
-        logits, caches = model.chunk_step(
+        logits, caches, *_ = model.chunk_step(
             params, caches, pt, tk,
             jnp.full((b,), start, jnp.int32),
             jnp.full((b,), c, jnp.int32),

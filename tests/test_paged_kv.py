@@ -9,8 +9,10 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.decode_attention import (
     paged_decode_attention,
+    paged_mla_decode_attention,
     pages_per_block,
 )
+from repro.models import attention as attn
 from repro.serving.kv_manager import PageAllocator, PagedKVManager
 
 
@@ -243,6 +245,55 @@ def test_paged_kernel_ignores_stale_pages():
     want = ref.decode_attention_ref(q, k_cont, v_cont, kv_len)
     got = paged_decode_attention(q, k_pois, v_pois, pt, kv_len,
                                  interpret=True)
+    _check_lanes(got, want, kv_len)
+
+
+# Latent attention's pool as Moonlight serves it: a 512-wide latent and
+# a 64-wide rotary key per token in a 640-wide row, 16 query heads,
+# 2048-token lanes.
+MLA_H, MLA_C, MLA_R, MLA_S = 16, 512, 64, 2048
+
+
+@pytest.mark.parametrize("shape", [
+    pytest.param((3, 4, 32, 8, 48, 40, 4), id="small"),
+    pytest.param((SERVED_B, MLA_H, MLA_C, MLA_R, 640, MLA_S, SERVED_PS),
+                 id="served"),
+])
+def test_paged_mla_decode_matches_expanded(shape):
+    """The MLA kernel (interpret mode) and the served jnp path against
+    attention written out over contiguous latents; the served shape has
+    a lane at each block boundary over a pool poisoned outside the live
+    tokens."""
+    b, h, dc, dr, width, s, ps = shape
+    served = b == SERVED_B
+    lens = None
+    if served:
+        bt = ps * pages_per_block(1, ps, width, jnp.float32, s // ps)
+        lens = [0, 1, ps, ps + 1, bt, bt + 1, s, min(2 * bt + 5, s - 1)]
+    kv, (lat, _, pages, _, kv_len) = _paged_fixture(
+        5, b, 1, s, width, ps, lens)
+    pages = pages.at[..., dc + dr:].set(0.0)   # the row's zero padding
+    k0, k1 = jax.random.split(jax.random.key(8))
+    q_c = jax.random.normal(k0, (b, h, dc)) * 0.05
+    q_r = jax.random.normal(k1, (b, h, dr)) * 0.05
+    scale = 1.0 / (dc ** 0.5)
+    c, r = lat[:, 0, :, :dc], lat[:, 0, :, dc:dc + dr]
+    sc = (jnp.einsum("bhc,bkc->bhk", q_c, c)
+          + jnp.einsum("bhr,bkr->bhk", q_r, r)) * scale
+    sc = jnp.where(jnp.arange(s)[None, None] < kv_len[:, None, None], sc,
+                   -jnp.inf)
+    want = jnp.einsum("bhk,bkc->bhc", jax.nn.softmax(sc, -1), c)
+    pt = jnp.asarray(kv.table)
+    got_jnp = attn.paged_mla_decode_attention(q_c, q_r, pages, pt, kv_len,
+                                              scale=scale)
+    on = np.asarray(kv_len) > 0
+    np.testing.assert_allclose(np.asarray(got_jnp)[on], np.asarray(want)[on],
+                               rtol=2e-5, atol=2e-5)
+    pages, _, pt = _poison_dead(kv, pages, pages, kv_len)
+    if served:
+        pt = pt.at[0].set(-1)  # the idle lane's table all -1
+    got = paged_mla_decode_attention(q_c, q_r, pages, pt, kv_len,
+                                     scale=scale, interpret=True)
     _check_lanes(got, want, kv_len)
 
 

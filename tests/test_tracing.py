@@ -112,6 +112,13 @@ def test_every_dispatching_step_runs_plan_launch_wait_commit(served):
     assert max(s[3]["k"] for s in steps) > 1   # a fused block ran
 
 
+def test_a_model_without_experts_carries_no_expert_load(served):
+    steps = [s for s in served["spans"] if s[0] == "hfx.engine.step"]
+    assert steps
+    assert not any({"expert_tokens", "experts_hit"} & set(s[3])
+                   for s in steps)
+
+
 def test_first_chunks_follow_one_submit_each(served):
     spans = served["spans"]
     submits = [s for s in spans if s[0] == "hfx.session.submit"]
