@@ -20,8 +20,9 @@ Two execution planes:
   prefill chunk with one decode iteration whenever both have work — so
   a long prompt never stalls in-flight decodes for more than one
   bounded chunk (the head-of-line blocking §5.1 schedules around).
-  Prefill chunks and decode share one jitted ``Model.chunk_step``
-  (decode is the chunk-length-1 case).
+  Prefill chunks and decode share ``Model.chunk_step`` (decode is the
+  chunk-length-1 case, over every lane; a chunk runs only its
+  prefilling rows where no state is slot-resident).
 
 - **Slot-based (fallback)**: monolithic full-prompt prefill into
   contiguous per-slot rows; kept for architectures the chunked plane
@@ -74,6 +75,12 @@ _STEP_IDS = itertools.count(1)
 
 def _rids(reqs) -> Optional[str]:
     return ";".join(str(r.rid) for r in reqs) or None  # a span argument
+
+
+def _on_rows(step, params, caches, table, rows, *args):
+    """``step`` over the page table's ``rows``, selected inside the
+    jitted call, so the selection adds no dispatch of its own."""
+    return step(params, caches, table[rows], *args)
 
 
 @dataclasses.dataclass
@@ -161,7 +168,17 @@ class InferenceEngine:
             self.axes = model.paged_cache_axes()
             if "chunk" not in cache:
                 cache["chunk"] = jax.jit(model.chunk_step)
+            if "prefill_chunk" not in cache:
+                cache["prefill_chunk"] = jax.jit(
+                    partial(_on_rows, model.chunk_step))
             self._chunk = cache["chunk"]
+            self._prefill_chunk = cache["prefill_chunk"]
+            # a prefill chunk's program runs chunk_rows rows, one per
+            # prefilling slot, where all of a model's state lives in the
+            # pages; SSM/conv state is slot-resident (mamba, hybrids),
+            # so their chunks run every slot's row (None)
+            self.chunk_rows = (min(cfg.prefill_batch, cfg.n_slots)
+                               if model.supports_prefix_cache else None)
             if cfg.prefix_cache:
                 if not model.supports_prefix_cache:
                     raise ValueError(
@@ -355,9 +372,10 @@ class InferenceEngine:
     def step(self) -> dict:
         """Run one prefill (chunk) or decode step; returns event info
         with the step's id.  Spans: ``hfx.engine.step`` (its ``kind``,
-        ``k``, ``tokens`` out, and for an expert model ``expert_tokens``
-        and ``experts_hit``) holds ``plan`` up to the dispatch, then
-        ``commit`` (``_dispatch``)."""
+        ``k``, ``tokens`` out; a prefill chunk's ``rows`` in its program
+        and ``live_rows`` with tokens; for an expert model
+        ``expert_tokens`` and ``experts_hit``) holds ``plan`` up to the
+        dispatch, then ``commit`` (``_dispatch``)."""
         self._step_id = next(_STEP_IDS)
         with tracing.span("hfx.engine.step",
                           step=self._step_id) as self._span:
@@ -498,29 +516,40 @@ class InferenceEngine:
                 self._preempt_youngest(exclude=oldest)
             return None
 
-        tokens = np.zeros((cfg.n_slots, cfg.chunk_size), np.int32)
-        start = np.array(self.pos)  # decode rows: frozen at cur pos
-        lens = np.zeros((cfg.n_slots,), np.int32)
+        if self.chunk_rows:
+            # one row per prefilling slot in admission order; padding
+            # rows (lens 0, so their writes drop) read the first one's
+            slots = list(self.prefilling)
+            slots += slots[:1] * (self.chunk_rows - len(slots))
+            start = np.zeros(len(slots), np.int32)
+        else:
+            slots = list(range(cfg.n_slots))
+            start = np.array(self.pos)  # decode rows: frozen at cur pos
+        row = {s: slots.index(s) for s in self.prefilling}
+        tokens = np.zeros((len(slots), cfg.chunk_size), np.int32)
+        lens = np.zeros((len(slots),), np.int32)
         for s, r in self.prefilling.items():
-            t = takes[s]
-            tokens[s, :t] = r.prompt[
+            i, t = row[s], takes[s]
+            tokens[i, :t] = r.prompt[
                 r.prefill_progress: r.prefill_progress + t
             ]
-            start[s] = r.prefill_progress
-            lens[s] = t
+            start[i] = r.prefill_progress
+            lens[i] = t
 
+        chunk_lens = [t for t in takes.values() if t > 0]
         (logits, self.caches, *load), dt = self._dispatch(
-            lambda: self._chunk(
+            lambda: self._prefill_chunk(
                 self.params, self.caches, self.kv.device_table(),
+                jnp.asarray(np.asarray(slots, np.int32)),
                 jnp.asarray(tokens), jnp.asarray(start), jnp.asarray(lens),
             ),
             # requests whose first chunk runs now (queue wait ends here)
             first_rids=lambda: _rids(
                 r for s, r in self.prefilling.items()
                 if takes[s] and r.prefill_progress == r.prefix_hit_tokens),
+            rows=len(slots), live_rows=len(chunk_lens),
         )
         self._annotate_load(load)
-        chunk_lens = [t for t in takes.values() if t > 0]
         self.profiler.observe_prefill(chunk_lens, dt)
         self.n_prefill_tokens += int(sum(chunk_lens))
 
@@ -533,7 +562,7 @@ class InferenceEngine:
                 # the slot's full-page prefix span is now immutable KV:
                 # publish it so later same-prefix prompts hit
                 self.kv.publish_prefix(s, r.prompt)
-                tok = int(nxt[s])
+                tok = int(nxt[row[s]])
                 if r.first_token_time is None:
                     r.first_token_time = self.clock
                 r.generated.append(tok)

@@ -19,6 +19,7 @@ import json
 import os
 import pathlib
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +33,7 @@ from repro.kernels.decode_attention import (
 )
 from repro.kernels.page_gather import page_gather
 from repro.models import build_model
+from repro.serving.engine import EngineConfig, _on_rows
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 import chip_smoke  # noqa: E402
@@ -39,6 +41,7 @@ import chip_smoke  # noqa: E402
 CUT = chip_smoke.qwen7b_cut()
 ENG = chip_smoke.ENGINE
 B, PS, CHUNK = ENG.n_slots, ENG.page_size, ENG.chunk_size
+P = min(ENG.prefill_batch, B)   # rows of a served prefill chunk
 MP = -(-ENG.max_len // PS)
 HQ, HKV, D = CUT.n_heads, CUT.n_kv_heads, CUT.resolved_head_dim
 
@@ -143,6 +146,27 @@ def test_chunk_step_fits_one_chip(served_model, one_chip, tpu_decision,
     assert ("tpu_custom_call" in c.as_text()) == (chunk == 1)
 
 
+def _compile_prefill_rows(model, params, caches, table, rows, chunk,
+                          sharding):
+    """The prefill chunk program as the engine serves it: ``rows`` rows
+    of ``chunk`` tokens, their rows of the ``table`` (lanes, pages)
+    picked inside the call."""
+    i32 = jnp.int32
+    vec = _spec((rows,), i32, sharding)
+    return jax.jit(partial(_on_rows, model.chunk_step)).lower(
+        params, caches, _spec(table, i32, sharding), vec,
+        _spec((rows, chunk), i32, sharding), vec, vec,
+    ).compile()
+
+
+def test_prefill_rows_fit_one_chip(served_model, one_chip, tpu_decision):
+    model, params, caches = served_model
+    c = _compile_prefill_rows(model, params, caches, (B, MP), P, CHUNK,
+                              one_chip)
+    _assert_fits(c)
+    assert "tpu_custom_call" not in c.as_text()
+
+
 def test_decode_block_fits_one_chip(served_model, one_chip, tpu_decision):
     model, params, caches = served_model
     i32, vec = jnp.int32, (B,)
@@ -177,6 +201,7 @@ MOONLIGHT = json.loads((BENCH / "configs" / "moonlight-16b-a3b.json")
                        .read_text())
 ML_ENG = MOONLIGHT["engine"]
 ML_B, ML_MP = ML_ENG["n_slots"], ML_ENG["max_len"] // ML_ENG["page_size"]
+ML_P = min(EngineConfig(**ML_ENG).prefill_batch, ML_B)
 
 
 def _moonlight_config():
@@ -191,9 +216,10 @@ def _moonlight_config():
 def moonlight(one_chip):
     """The served cut (1 dense + 10 expert layers of 8 held experts, 32
     lanes of 2048 tokens) in float32, as shapes on one chip: the record
-    behind the depth and the lanes.  One more expert layer takes the
-    prefill chunk program to 15.85 GB; 48 lanes at 8 expert layers, to
-    16.48 GB."""
+    behind the depth and the lanes.  The largest program is the fused
+    decode block, 13.08 GB (the served 4-row prefill chunk 11.18, the
+    chunk over all 32 rows 12.18); 48 lanes take the block to 15.93 GB,
+    past the chip."""
     model = build_model(_moonlight_config())
     params = _on(model.abstract_params(), one_chip)
     caches = _on(jax.eval_shape(lambda: model.init_paged_cache(
@@ -235,6 +261,16 @@ def test_moonlight_chunk_step_fits_one_chip(moonlight, one_chip,
     # one-token decode runs the MLA kernel; prefill chunks run jnp (both
     # run the experts as ragged dots, custom calls of their own)
     assert ("paged_mla_decode_attention" in c.as_text()) == (chunk == 1)
+    assert "ragged-dot" in c.as_text()
+
+
+def test_moonlight_prefill_rows_fit_one_chip(moonlight, one_chip,
+                                            tpu_decision):
+    model, params, caches = moonlight
+    c = _compile_prefill_rows(model, params, caches, (ML_B, ML_MP), ML_P,
+                              ML_ENG["chunk_size"], one_chip)
+    _assert_fits(c)
+    assert "paged_mla_decode_attention" not in c.as_text()
     assert "ragged-dot" in c.as_text()
 
 

@@ -112,6 +112,22 @@ def test_every_dispatching_step_runs_plan_launch_wait_commit(served):
     assert max(s[3]["k"] for s in steps) > 1   # a fused block ran
 
 
+def test_prefill_chunks_carry_rows_and_live_rows(served):
+    """A prefill chunk's program runs ``prefill_batch`` rows, of which
+    ``live_rows`` carry prompt tokens; decode steps carry neither."""
+    eng = EngineConfig.smoke()
+    steps = [s[3] for s in served["spans"] if s[0] == "hfx.engine.step"]
+    chunks = [a for a in steps if a.get("kind") == "prefill_chunk"]
+    assert chunks
+    for a in chunks:
+        assert a["rows"] == eng.prefill_batch, a
+        assert 1 <= a["live_rows"] <= a["rows"], a
+        assert a["tokens"] <= a["rows"] * eng.chunk_size, a
+    assert max(a["live_rows"] for a in chunks) == eng.prefill_batch
+    assert not any({"rows", "live_rows"} & set(a) for a in steps
+                   if a.get("kind") == "decode")
+
+
 def test_a_model_without_experts_carries_no_expert_load(served):
     steps = [s for s in served["spans"] if s[0] == "hfx.engine.step"]
     assert steps
