@@ -2,10 +2,10 @@
 
 Pure-decode micro-bench on the real engine (CPU smoke config): short
 prompts decode long outputs with an empty queue, swept over the
-``decode_block`` ceiling K.  K=1 is the per-token baseline — every
-generated token pays one jit dispatch, one host sync, and (without the
-device-resident mirrors) a pos/last_token/page-table upload; a K-block
-pays all of that once per K tokens.
+``decode_block`` ceiling K.  Every decode runs as a block of K
+iterations, the same scan at every K: each block pays one jit dispatch
+and one host sync, so K=1 (a block of one per token) is the baseline
+and a K-block pays them once per K tokens.
 
 Reports decode tokens/s, jitted dispatches (= host syncs) per token,
 the block-size histogram, and greedy token-identity vs the K=1 run.

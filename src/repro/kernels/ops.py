@@ -4,8 +4,8 @@ Three kernels sit on the served path, and all run compiled exactly when
 JAX's default backend is a TPU:
 
 - ``paged_decode_attention`` — the paged plane's one-token decode
-  (``Model._attn_block``: every K=1 decode and every ``decode_block``
-  iteration): one grid step walks each lane's live blocks of pages,
+  (``Model._attn_block``: every ``decode_block`` iteration): one grid
+  step walks each lane's live blocks of pages,
   fetching each live page (all KV heads) by its own DMA, double-
   buffered, and serves a KV head's query heads together; idle lanes
   and pages past a lane's length are never read;

@@ -40,7 +40,6 @@ from repro.models.common import (
     rms_norm,
     split_keys,
     swiglu,
-    unembed,
 )
 
 ATTN_KINDS = ("dense", "moe", "encoder", "local", "global", "shared_attn",
@@ -52,6 +51,20 @@ N_AUX = 3   # balance loss, expert_tokens, experts_hit
 
 def _no_aux():
     return jnp.zeros((N_AUX,), jnp.float32)
+
+
+def _at_last(x, lens):
+    """Each row's hidden state at its last valid position."""
+    idx = jnp.clip(lens - 1, 0, x.shape[1] - 1)
+    return jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
+
+
+def _stops(tok, new_pos, new_rem, eos, max_len):
+    """The decode programs' stopping predicate, on the device, after a
+    token is appended (host twin: ``InferenceEngine._is_done``): output
+    cap reached, EOS emitted, or no room for another token's KV within
+    ``max_len``."""
+    return (new_rem <= 0) | (tok == eos) | (new_pos + 1 >= max_len)
 
 
 def _at_precision(fn):
@@ -583,6 +596,13 @@ class Model:
             x = embed(batch["tokens"], params["embed"], self.compute_dtype)
         return x
 
+    def _head(self, params, x):
+        """Vocabulary logits of hidden states ``x``: the tied embedding
+        or the separate head."""
+        table = (params["embed"] if self.cfg.tie_embeddings
+                 else params["head"])
+        return x @ table.T.astype(x.dtype)
+
     @_at_precision
     def forward(self, params, batch, return_aux: bool = False):
         """Full-sequence forward -> logits (B, S, V)."""
@@ -596,8 +616,7 @@ class Model:
             make_cache=False, cache_len=s, decode=False,
         )
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        table = params["embed"] if cfg.tie_embeddings else params["head"]
-        logits = unembed(x, table)
+        logits = constrain(self._head(params, x), "batch", "seq", "vocab")
         if return_aux:
             return logits, aux
         return logits
@@ -637,11 +656,7 @@ class Model:
             decode=False,
         )
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        idx = jnp.clip(lens - 1, 0, s - 1)
-        x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-        table = params["embed"] if cfg.tie_embeddings else params["head"]
-        logits = x_last @ table.T.astype(x_last.dtype)
-        return logits, caches
+        return self._head(params, _at_last(x, lens)), caches
 
     @property
     def supports_chunked(self) -> bool:
@@ -673,33 +688,38 @@ class Model:
         refused for them — mirroring ``supports_prefix_cache``."""
         return self.supports_prefix_cache
 
-    @_at_precision
-    def chunk_step(self, params, caches, page_table, tokens, start,
-                   chunk_lens):
-        """Unified chunked-prefill / decode step over *paged* caches.
-
-        tokens: (B, C) right-padded chunk tokens; start: (B,) absolute
-        position of each row's first token; chunk_lens: (B,) valid
-        counts — 0 freezes a row (writes dropped, SSM state held), so
-        idle decode slots ride along in the same jitted call.
-        page_table: (B, MP) int32.  Returns (logits (B, V) at each
-        row's last valid token, new caches); decode is the C == 1
-        special case.
-        """
-        cfg = self.cfg
+    def _paged_forward(self, params, caches, page_table, tokens, start,
+                       lens):
+        """The paged plane's forward: embed ``tokens`` (B, C) at
+        positions ``start + i``, run every segment over the paged
+        caches with ``lens`` (B,) valid tokens per row (0 freezes a
+        row: writes dropped, SSM state held), final norm.  Returns
+        (hidden states (B, C, D), new caches, aux)."""
         x = embed(tokens, params["embed"], self.compute_dtype)
-        b, c = tokens.shape
-        positions = start[:, None] + jnp.arange(c)[None, :]
+        positions = start[:, None] + jnp.arange(tokens.shape[1])[None, :]
         x, new_caches, aux = self._run_segments(
-            params, x, positions=positions, lens=chunk_lens, caches=caches,
+            params, x, positions=positions, lens=lens, caches=caches,
             make_cache=True, cache_len=0, decode=False, chunked=True,
             page_table=page_table,
         )
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        idx = jnp.clip(chunk_lens - 1, 0, c - 1)
-        x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-        table = params["embed"] if cfg.tie_embeddings else params["head"]
-        logits = x_last @ table.T.astype(x_last.dtype)
+        return (rms_norm(x, params["final_norm"], self.cfg.norm_eps),
+                new_caches, aux)
+
+    @_at_precision
+    def chunk_step(self, params, caches, page_table, tokens, start,
+                   chunk_lens):
+        """One step over *paged* caches: a prefill chunk, or (C == 1)
+        the body of a decode block.
+
+        tokens: (B, C) right-padded chunk tokens; start: (B,) absolute
+        position of each row's first token; chunk_lens: (B,) valid
+        counts — 0 freezes a row, so idle slots ride along in the same
+        jitted call.  page_table: (B, MP) int32.  Returns (logits
+        (B, V) at each row's last valid token, new caches).
+        """
+        x, new_caches, aux = self._paged_forward(
+            params, caches, page_table, tokens, start, chunk_lens)
+        logits = self._head(params, _at_last(x, chunk_lens))
         return (logits, new_caches) + self._expert_load(aux)
 
     def _expert_load(self, aux) -> tuple:
@@ -709,18 +729,18 @@ class Model:
             return ()
         return (aux[1:].astype(jnp.int32),)
 
-    # -- fused decode blocks ---------------------------------------------------
+    # -- decode blocks ---------------------------------------------------------
     #
-    # One jitted dispatch runs K greedy decode iterations in a
+    # Every decode dispatch runs K >= 1 greedy iterations in a
     # ``lax.scan`` whose carry holds the caches AND the generation
     # state (last token, position, alive mask, remaining-output
-    # budget), so the per-token host round-trip — upload pos/token,
-    # dispatch, block, download logits — is paid once per K tokens.
-    # Stopping (EOS, max-len, per-request l_out) is evaluated on
-    # device: a row that finishes mid-block freezes (its chunk length
-    # drops to 0, so cache writes are dropped / become idempotent and
-    # its later lanes are marked invalid), mirroring the host-side
-    # ``InferenceEngine._is_done`` predicate exactly.
+    # budget), so the host round-trip — dispatch, sync, download
+    # tokens — is paid once per K tokens, and the state stays on the
+    # device from one block to the next.  Stopping (EOS, max-len,
+    # per-request l_out) is evaluated on device by ``_stops``: a row
+    # that finishes mid-block freezes (its chunk length drops to 0, so
+    # cache writes are dropped / become idempotent and its later lanes
+    # are marked invalid).
 
     def _decode_block_body(self, last, pos, alive, rem, eos, max_len,
                            logits):
@@ -730,11 +750,7 @@ class Model:
         tok = jnp.where(alive, nxt, last)      # frozen rows keep state
         new_pos = pos + step
         new_rem = rem - step
-        # same predicate as the per-token path applies after appending
-        # a token: output cap hit, EOS emitted, or no room for another
-        # token's KV within max_len
-        done = (new_rem <= 0) | (tok == eos) | (new_pos + 1 >= max_len)
-        new_alive = alive & ~done
+        new_alive = alive & ~_stops(tok, new_pos, new_rem, eos, max_len)
         return tok, new_pos, new_alive, new_rem
 
     def decode_block(self, params, caches, page_table, last, pos, alive,
@@ -743,8 +759,8 @@ class Model:
 
         last/pos/rem: (B,) int32 device state; alive: (B,) bool (False
         rows — idle or mid-prefill slots — are frozen: zero chunk
-        length drops their writes); eos: scalar int32 (-1 disables);
-        max_len: scalar int32; ``k`` is static (jit per block size).
+        length drops their writes); eos: int32 (-1 disables); max_len:
+        int32; ``k`` is static (jit per block size).
         Returns ``(tokens (B, K), valid (B, K), last, pos), caches``
         (a model with an expert layer adds its load summed over the
         block after ``pos``) —
@@ -779,8 +795,7 @@ class Model:
         keeps re-running its *last* token at its *frozen* position —
         attention cache writes become idempotent overwrites and the
         row's lanes are marked invalid (its SSM state self-pollutes
-        harmlessly: the engine clears the row at retire, exactly as the
-        per-token path does).
+        harmlessly: the engine clears the row at retire).
         """
         def body(carry, _):
             caches, last, pos, alive, rem = carry
@@ -798,7 +813,7 @@ class Model:
 
     @_at_precision
     def spec_decode_block(self, params, caches, page_table, last, pos,
-                          alive, rem, eos, max_len, props, prop_lens,
+                          alive, rem, props, prop_lens, eos, max_len,
                           *, k: int):
         """One propose-verify-accept speculative dispatch over *paged*
         caches: score ``last`` plus up to ``k`` drafted tokens in a
@@ -813,26 +828,18 @@ class Model:
         ``i`` plain decode steps would emit, as long as every earlier
         proposal matched.  The same on-device stopping predicate as
         :meth:`decode_block` runs per lane, so EOS / l_out / max_len
-        cut the accepted span exactly where per-token decode would
+        cut the accepted span exactly where a decode block would
         stop.  Returns ``(tokens (B, K+1), valid (B, K+1), last, pos),
         caches``; ``valid`` is prefix-contiguous per row and the
         caller rolls rejected lanes' KV back by truncating the page
         table to the returned ``pos``.
         """
-        cfg = self.cfg
         b = last.shape[0]
         tokens = jnp.concatenate([last[:, None], props], axis=1)
         chunk_lens = jnp.where(alive, 1 + prop_lens, 0).astype(jnp.int32)
-        x = embed(tokens, params["embed"], self.compute_dtype)
-        positions = pos[:, None] + jnp.arange(k + 1)[None, :]
-        x, new_caches, aux = self._run_segments(
-            params, x, positions=positions, lens=chunk_lens, caches=caches,
-            make_cache=True, cache_len=0, decode=False, chunked=True,
-            page_table=page_table,
-        )
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        table = params["embed"] if cfg.tie_embeddings else params["head"]
-        logits = x @ table.T.astype(x.dtype)          # (B, K+1, V)
+        x, new_caches, aux = self._paged_forward(
+            params, caches, page_table, tokens, pos, chunk_lens)
+        logits = self._head(params, x)                # (B, K+1, V)
         t = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         idx = jnp.arange(k + 1)[None, :]
         # lane i+1 is reachable iff proposal i matched the greedy pick
@@ -845,10 +852,9 @@ class Model:
             axis=1,
         )
         # per-lane stopping, evaluated as if the lane's token had been
-        # appended by a plain decode step (mirrors _decode_block_body)
-        new_pos_i = pos[:, None] + idx + 1
-        new_rem_i = rem[:, None] - (idx + 1)
-        done_i = (new_rem_i <= 0) | (t == eos) | (new_pos_i + 1 >= max_len)
+        # appended by a decode block
+        done_i = _stops(t, pos[:, None] + idx + 1, rem[:, None] - (idx + 1),
+                        eos, max_len)
         stopped_before = jnp.concatenate(
             [jnp.zeros((b, 1), bool),
              jnp.cumsum(done_i.astype(jnp.int32), axis=1)[:, :-1] > 0],
@@ -871,7 +877,6 @@ class Model:
         """
         cfg = self.cfg
         x = embed(tokens[:, None], params["embed"], self.compute_dtype)
-        b = x.shape[0]
         positions = pos[:, None]
         lens = pos + 1
         x, new_caches, _ = self._run_segments(
@@ -879,9 +884,7 @@ class Model:
             make_cache=False, cache_len=0, decode=True,
         )
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        table = params["embed"] if cfg.tie_embeddings else params["head"]
-        logits = x[:, 0] @ table.T.astype(x.dtype)
-        return logits, new_caches
+        return self._head(params, x[:, 0]), new_caches
 
     # -- cache allocation (for the real serving engine & dry-run specs) -------
     def init_cache(self, batch_size: int, max_len: int):

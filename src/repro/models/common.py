@@ -53,12 +53,6 @@ def embed(tokens: jax.Array, table: jax.Array, dtype) -> jax.Array:
     return constrain(out, "batch", "seq", "embed")
 
 
-def unembed(x: jax.Array, table: jax.Array) -> jax.Array:
-    """Project hidden states to (sharded) vocab logits."""
-    logits = x @ table.T.astype(x.dtype)
-    return constrain(logits, "batch", "seq", "vocab")
-
-
 def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
     """Token-mean cross entropy; stable over a (possibly sharded) vocab."""
     logits = logits.astype(jnp.float32)

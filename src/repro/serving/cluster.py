@@ -340,10 +340,11 @@ class Cluster:
         warm.submit(Request.from_prompt(
             -1, np.arange(1, n_warm + 1, dtype=np.int32), max_new=2))
         warm.run_until_done(max_steps=64)
-        # the fused decode-block jits (one per power-of-two K bucket)
-        # compile here too — a tiny warm request never reaches K > 1,
-        # and the first real block must not pay XLA inside a measured
-        # step (it would pollute TTFTs and the Eq. 5 fit)
+        # every decode program (one per power-of-two K, and per
+        # verify bucket) compiles here too — a tiny warm request never
+        # reaches K > 1, and the first real block must not pay XLA
+        # inside a measured step (it would pollute TTFTs and the Eq. 5
+        # fit)
         warm.warm_decode_blocks()
         if self.cfg.mode == "pd" and not warm.paged:
             raise ValueError(

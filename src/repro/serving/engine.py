@@ -20,9 +20,9 @@ Two execution planes:
   prefill chunk with one decode iteration whenever both have work — so
   a long prompt never stalls in-flight decodes for more than one
   bounded chunk (the head-of-line blocking §5.1 schedules around).
-  Prefill chunks and decode share ``Model.chunk_step`` (decode is the
-  chunk-length-1 case, over every lane; a chunk runs only its
-  prefilling rows where no state is slot-resident).
+  Prefill chunks and decode share ``Model.chunk_step``: a decode
+  block scans it at chunk length 1 over every lane, and a chunk runs
+  only its prefilling rows where no state is slot-resident.
 
 - **Slot-based (fallback)**: monolithic full-prompt prefill into
   contiguous per-slot rows; kept for architectures the chunked plane
@@ -96,8 +96,8 @@ class EngineConfig:
     n_pages: Optional[int] = None   # default: n_slots * ceil(max_len/ps)
     chunk_size: int = 32            # static ceiling per prefill chunk
     # fused decode blocks: max decode iterations per jitted dispatch
-    # (one host sync per block instead of per token).  1 = legacy
-    # per-token stepping; the engine collapses to 1 under queue
+    # (one host sync per block instead of per token).  Every decode
+    # runs as a block; the engine collapses K to 1 under queue
     # pressure so chunked prefill keeps its Eq. 5 interleave turn.
     decode_block: int = 8
     # prefix cache: page-level KV reuse across requests (paged plane,
@@ -166,12 +166,9 @@ class InferenceEngine:
                 cfg.page_size, self.kv.n_pages,
             )
             self.axes = model.paged_cache_axes()
-            if "chunk" not in cache:
-                cache["chunk"] = jax.jit(model.chunk_step)
             if "prefill_chunk" not in cache:
                 cache["prefill_chunk"] = jax.jit(
                     partial(_on_rows, model.chunk_step))
-            self._chunk = cache["chunk"]
             self._prefill_chunk = cache["prefill_chunk"]
             # a prefill chunk's program runs chunk_rows rows, one per
             # prefilling slot, where all of a model's state lives in the
@@ -206,9 +203,6 @@ class InferenceEngine:
                 model.init_cache, cfg.n_slots, cfg.max_len
             )
             self.axes = model.cache_axes()
-            if "decode" not in cache:
-                cache["decode"] = jax.jit(model.decode_step)
-            self._decode = cache["decode"]
         self.queue: list[Request] = []
         self.prefilling: dict[int, Request] = {}  # slot -> req
         self.active: dict[int, Request] = {}
@@ -230,9 +224,17 @@ class InferenceEngine:
         self._prefill_fns: dict[int, Callable] = cache.setdefault(
             "prefill", {}
         )
-        # fused decode blocks: jitted scan per (plane, K) bucket
-        self._block_fns: dict[tuple, Callable] = cache.setdefault(
-            "decode_block", {}
+        # the decode programs, one jitted scan per K: a fused block on
+        # this plane, and a verify pass per proposal-width bucket.  The
+        # stopping constants are bound in, so a fn_cache shared by
+        # replicas shares a program only where they agree
+        eos = -1 if cfg.eos_token is None else cfg.eos_token
+        self._stop = {"eos": eos, "max_len": cfg.max_len}
+        self._block_fns: dict[int, Callable] = cache.setdefault(
+            ("decode_block", self.paged, eos, cfg.max_len), {}
+        )
+        self._spec_fns: dict[int, Callable] = cache.setdefault(
+            ("spec_block", eos, cfg.max_len), {}
         )
         self._turn = "prefill"  # round-robin fairness when both planes busy
         self._seq = 0           # submit-order stamp (preemption age)
@@ -252,13 +254,9 @@ class InferenceEngine:
         # (cache hits skip prefill compute, so with a prefix cache this
         # undercounts l_in — exactly the FLOPs-saved figure)
         self.decode_block_hist: dict[int, int] = {}  # K -> n blocks
-        # speculative decoding: drafter + jitted verify fns per pow2
-        # proposal-width bucket, and acceptance telemetry
+        # speculative decoding: drafter and acceptance telemetry
         self.drafter: Optional[NGramDrafter] = None
         self._spec_cfg: Optional[SpecConfig] = None
-        self._spec_fns: dict[int, Callable] = cache.setdefault(
-            "spec_block", {}
-        )
         self.n_spec_dispatches = 0   # propose-verify dispatches
         self.n_spec_proposed = 0     # drafted tokens sent to verify
         self.n_spec_accepted = 0     # drafted tokens accepted
@@ -426,7 +424,7 @@ class InferenceEngine:
         if admitted:
             return self._prefill(admitted)
         if self.active:
-            return self._decode_step()
+            return self._decode_block_step(self._decode_block_k())
         return {"kind": "idle"}
 
     # ==========================================================================
@@ -777,8 +775,8 @@ class InferenceEngine:
 
     def _fit_block_k(self, k: int) -> int:
         """Shrink K (halving) until pre-reserving pages for K new
-        tokens per active slot fits the free pool; at 1 the legacy
-        ensure/preempt-youngest fallback takes over."""
+        tokens per active slot fits the free pool; at 1 the caller's
+        ensure/preempt-youngest loop makes room."""
         ps = self.cfg.page_size
         while k > 1:
             need = 0
@@ -793,12 +791,17 @@ class InferenceEngine:
         return 1
 
     def _decode_block_fn(self, k: int) -> Callable:
-        key = ("paged" if self.paged else "slot", k)
-        if key not in self._block_fns:
+        if k not in self._block_fns:
             fn = (self.model.decode_block if self.paged
                   else self.model.decode_block_slots)
-            self._block_fns[key] = jax.jit(partial(fn, k=k))
-        return self._block_fns[key]
+            self._block_fns[k] = jax.jit(partial(fn, k=k, **self._stop))
+        return self._block_fns[k]
+
+    def _spec_block_fn(self, k: int) -> Callable:
+        if k not in self._spec_fns:
+            self._spec_fns[k] = jax.jit(partial(
+                self.model.spec_decode_block, k=k, **self._stop))
+        return self._spec_fns[k]
 
     def _device_state(self) -> tuple:
         """(last_token, pos) as device-resident arrays.  The previous
@@ -812,47 +815,44 @@ class InferenceEngine:
             self._host_state_dirty = False
         return self._dev_state
 
-    def warm_decode_blocks(self) -> None:
-        """Compile the power-of-two decode-block jits up front.  The
-        calls are pure with an all-frozen batch (outputs discarded,
-        engine state untouched), so XLA compile time never lands
-        inside a measured step."""
-        cfg = self.cfg
-        zeros = jnp.zeros((cfg.n_slots,), jnp.int32)
-        alive = jnp.zeros((cfg.n_slots,), bool)
-        k = 2
-        while k <= max(1, cfg.decode_block):
-            fn = self._decode_block_fn(k)
-            args = (self.params, self.caches)
-            if self.paged:
-                args += (self.kv.device_table(),)
-            out, _ = fn(*args, zeros, zeros, alive, zeros + 1,
-                        jnp.int32(-1), jnp.int32(cfg.max_len))
-            jax.block_until_ready(out)
-            k *= 2
-        if cfg.spec_decode:
-            # verify dispatches land in pow2 proposal-width buckets;
-            # warm every bucket up to the max_spec_len ceiling so the
-            # first speculative step never pays an XLA compile
-            k = 1
-            while True:
-                fn = self._spec_block_fn(k)
-                out, _ = fn(
-                    self.params, self.caches, self.kv.device_table(),
-                    zeros, zeros, alive, zeros + 1, jnp.int32(-1),
-                    jnp.int32(cfg.max_len),
-                    jnp.zeros((cfg.n_slots, k), jnp.int32), zeros,
-                )
-                jax.block_until_ready(out)
-                if k >= cfg.max_spec_len:
-                    break
-                k *= 2
+    def _launch_block(self, fn: Callable, state, alive, rem, *extra):
+        """Call a decode program (a fused block or a verify pass) the
+        one way the engine does: over the caches and the page table,
+        from the device ``state`` (last_token, pos), with ``alive``,
+        ``rem`` and ``extra`` uploaded from the host."""
+        args = (self.params, self.caches)
+        if self.paged:
+            args += (self.kv.device_table(),)
+        return fn(*args, *state, jnp.asarray(alive), jnp.asarray(rem),
+                  *map(jnp.asarray, extra))
 
-    def _spec_block_fn(self, k: int) -> Callable:
-        if k not in self._spec_fns:
-            fn = self.model.spec_decode_block
-            self._spec_fns[k] = jax.jit(partial(fn, k=k))
-        return self._spec_fns[k]
+    def warm_decode_blocks(self) -> None:
+        """Compile every decode program the served path can call: the
+        fused block of each power-of-two K up to ``decode_block`` and,
+        with speculation, each verify bucket up to ``max_spec_len``.
+        Each is called as the served path calls it, first from
+        ``_device_state()``, then from its own output state, as the
+        next block enters it.  Every lane is frozen and the outputs
+        are dropped, so the engine's state and pool are untouched, and
+        XLA compile time never lands inside a measured step."""
+        cfg = self.cfg
+        n = cfg.n_slots
+        frozen = (np.zeros(n, bool), np.zeros(n, np.int32))
+        calls = [(self._decode_block_fn(1 << i), ())
+                 for i in range(cfg.decode_block.bit_length())]
+        if cfg.spec_decode:
+            # proposal widths round up to a power of two
+            calls += [(self._spec_block_fn(1 << i),
+                       (np.zeros((n, 1 << i), np.int32),
+                        np.zeros(n, np.int32)))
+                      for i in range((cfg.max_spec_len - 1).bit_length()
+                                     + 1)]
+        for fn, extra in calls:
+            state = self._device_state()
+            for _ in range(2):   # the output caches are dropped at once
+                state = self._launch_block(fn, state, *frozen,
+                                           *extra)[0][2:4]
+            jax.block_until_ready(state)
 
     def _spec_history(self, r: Request) -> list[int]:
         """The request's true token sequence (prompt + generated).
@@ -874,7 +874,7 @@ class InferenceEngine:
         KV is rolled back by truncating the page table to the accepted
         position.  Returns None when nothing proposes or the page pool
         can't cover the proposals even at depth 1 — the caller falls
-        through to the plain block/per-token path.
+        through to a plain decode block.
         """
         cfg = self.cfg
         ps = cfg.page_size
@@ -921,27 +921,16 @@ class InferenceEngine:
         kpad = 1 << (kmax - 1).bit_length()  # pow2 compile bucket
         props = np.zeros((cfg.n_slots, kpad), np.int32)
         prop_lens = np.zeros(cfg.n_slots, np.int32)
-        alive = np.zeros(cfg.n_slots, bool)
-        rem = np.zeros(cfg.n_slots, np.int32)
-        pos0: dict[int, int] = {}
-        for s, r in self.active.items():
-            alive[s] = True
-            rem[s] = r.l_out - len(r.generated)
-            pos0[s] = int(self.pos[s])
+        for s in self.active:
             d = drafts[s][: plen[s]]
             props[s, : len(d)] = d
             prop_lens[s] = len(d)
-
-        last_d, pos_d = self._device_state()
-        eos = jnp.int32(-1 if cfg.eos_token is None else cfg.eos_token)
+        alive, rem, pos0 = self._lanes()
+        state = self._device_state()
         fn = self._spec_block_fn(kpad)
         (out, self.caches), dt = self._dispatch(
-            lambda: fn(
-                self.params, self.caches, self.kv.device_table(),
-                last_d, pos_d, jnp.asarray(alive), jnp.asarray(rem),
-                eos, jnp.int32(cfg.max_len),
-                jnp.asarray(props), jnp.asarray(prop_lens),
-            ))
+            lambda: self._launch_block(fn, state, alive, rem, props,
+                                       prop_lens))
         self.n_spec_dispatches += 1
         self.spec_depth_hist[kpad] = self.spec_depth_hist.get(kpad, 0) + 1
 
@@ -965,29 +954,29 @@ class InferenceEngine:
         return {**self._commit_block(out, kpad + 1, dt, pos0, on_lane),
                 "spec": True}
 
-    def _decode_block_step(self, k: int) -> dict:
-        """One fused K-iteration decode block (either plane): a single
-        jitted dispatch and a single host sync cover K tokens for every
-        active slot, with EOS / max-len / l_out stopping evaluated on
-        device (a row finishing mid-block freezes and its later lanes
-        come back invalid)."""
-        cfg = self.cfg
-        alive = np.zeros(cfg.n_slots, bool)
-        rem = np.zeros(cfg.n_slots, np.int32)
+    def _lanes(self) -> tuple:
+        """The decode batch as host arrays: ``alive`` and ``rem``
+        (outputs left) per slot, and each active slot's position."""
+        alive = np.zeros(self.cfg.n_slots, bool)
+        rem = np.zeros(self.cfg.n_slots, np.int32)
         pos0: dict[int, int] = {}
         for s, r in self.active.items():
             alive[s] = True
             rem[s] = r.l_out - len(r.generated)
             pos0[s] = int(self.pos[s])
-        last_d, pos_d = self._device_state()
-        eos = jnp.int32(-1 if cfg.eos_token is None else cfg.eos_token)
+        return alive, rem, pos0
+
+    def _decode_block_step(self, k: int) -> dict:
+        """One decode block of K iterations (either plane, K = 1
+        included): a single jitted dispatch and a single host sync
+        cover K tokens for every active slot, with EOS / max-len /
+        l_out stopping evaluated on device (a row finishing mid-block
+        freezes and its later lanes come back invalid)."""
+        alive, rem, pos0 = self._lanes()
+        state = self._device_state()
         fn = self._decode_block_fn(k)
-        args = (self.params, self.caches)
-        if self.paged:
-            args += (self.kv.device_table(),)
         (out, self.caches), dt = self._dispatch(
-            lambda: fn(*args, last_d, pos_d, jnp.asarray(alive),
-                       jnp.asarray(rem), eos, jnp.int32(cfg.max_len)))
+            lambda: self._launch_block(fn, state, alive, rem))
         self.decode_block_hist[k] = self.decode_block_hist.get(k, 0) + 1
         return self._commit_block(out, k, dt, pos0)
 
@@ -1004,7 +993,6 @@ class InferenceEngine:
         self._host_state_dirty = False
         tk = np.asarray(toks)   # (n_slots, K)
         vd = np.asarray(valid)  # (n_slots, K) bool
-        t_start = self.clock - dt
         finish_at: dict[int, float] = {}
         tok_ev: list[tuple] = []  # (rid, token, t) stream events
         n_emitted = 0
@@ -1020,15 +1008,16 @@ class InferenceEngine:
             self.pos[s] += len(emitted)
             self.last_token[s] = emitted[-1]
             n_emitted += len(emitted)
-            # per-token timestamps interpolate inside the block, so
-            # TTFT/TPOT (and the streamed token stamps) stay comparable
-            # with per-step runs / the sim — no extra host syncs: the
-            # block's one sync already delivered the (n_slots, K) matrix
-            for tok, lane in zip(emitted, lanes):
-                tok_ev.append((r.rid, tok, t_start + dt * (lane + 1) / k))
-            finish_at[s] = t_start + dt * (int(lanes[-1]) + 1) / k
+            # token stamps interpolate inside the block (lane K-1 lands
+            # at the engine clock), so TTFT/TPOT and the streamed stamps
+            # do not depend on K — no extra host syncs: the block's one
+            # sync already delivered the (n_slots, K) matrix
+            stamps = self.clock - dt * (k - 1 - lanes) / k
+            tok_ev += [(r.rid, tok, float(t))
+                       for tok, t in zip(emitted, stamps)]
+            finish_at[s] = float(stamps[-1])
         # Appendix-A attribution: K per-iteration samples of dt/K at
-        # the interpolated lengths (what per-token stepping observes);
+        # the interpolated lengths (what a block of one observes);
         # a verify's trailing all-rejected lanes are trimmed there, so
         # rejected speculation never biases the Eq. 2 fit low
         self.profiler.observe_decode_block(
@@ -1053,7 +1042,7 @@ class InferenceEngine:
         k = self._fit_block_k(self._decode_block_k())
         # page pre-reservation: every active slot gets room for K new
         # tokens; _fit_block_k guarantees this fits for K > 1, and at
-        # K == 1 the legacy preempt-youngest fallback reclaims pages
+        # K == 1 preempting the youngest request reclaims pages
         for s in list(self.active):
             if s not in self.active:  # evicted by an earlier preemption
                 continue
@@ -1065,16 +1054,7 @@ class InferenceEngine:
                         "page pool exhausted with a single request in "
                         "flight — submit() sizing guard violated"
                     )
-        if k > 1:
-            return self._decode_block_step(k)
-        lens = np.zeros((cfg.n_slots,), np.int32)
-        for s in self.active:
-            lens[s] = 1  # the new token lands at position pos[s]
-        return self._per_token_decode(lambda: self._chunk(
-            self.params, self.caches, self.kv.device_table(),
-            jnp.asarray(self.last_token[:, None]),
-            jnp.asarray(self.pos), jnp.asarray(lens),
-        ))
+        return self._decode_block_step(k)
 
     # ==========================================================================
     # Slot-based plane (monolithic prefill fallback)
@@ -1156,46 +1136,13 @@ class InferenceEngine:
         return {"kind": "prefill", "n": b, "time": dt,
                 "token_events": tok_ev}
 
-    def _decode_step(self) -> dict:
-        k = self._decode_block_k()
-        if k > 1:
-            return self._decode_block_step(k)
-        return self._per_token_decode(lambda: self._decode(
-            self.params, self.caches, jnp.asarray(self.last_token),
-            jnp.asarray(self.pos)))
-
-    def _per_token_decode(self, call: Callable) -> dict:
-        """Shared K=1 step for both planes: dispatch ``call`` (logits,
-        caches), append the sampled token per active slot, advance host
-        state, account telemetry, and retire — one place to keep the
-        paged/slot paths in sync."""
-        n_tok = len(self.active)
-        (logits, self.caches, *load), dt = self._dispatch(call)
-        self._annotate_load(load)
-        self.profiler.observe_decode(
-            [int(self.pos[s]) for s in self.active], dt)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        tok_ev: list[tuple] = []
-        for s, r in list(self.active.items()):
-            self.pos[s] += 1
-            tok = int(nxt[s])
-            r.generated.append(tok)
-            r.tokens_done = len(r.generated)
-            self.last_token[s] = tok
-            tok_ev.append((r.rid, tok, self.clock))
-        self._host_state_dirty = True
-        self.decode_block_hist[1] = self.decode_block_hist.get(1, 0) + 1
-        self.n_decode_tokens += n_tok
-        self._retire()
-        return {"kind": "decode", "n": n_tok, "k": 1,
-                "tokens": n_tok, "time": dt, "token_events": tok_ev}
-
     # -- completion (both planes) ----------------------------------------------
     def _is_done(self, r: Request, s: int) -> bool:
-        """The one completion predicate — shared by ``_retire``, the
-        chunk-prefill park decision, and (mirrored in jnp) the
-        decode-block device mask: output cap reached, EOS emitted, or
-        no room for another token's KV within max_len."""
+        """The one completion predicate — shared by ``_retire`` and the
+        chunk-prefill park decision, and mirrored in jnp on the device
+        by the decode programs (``repro.models.build._stops``): output
+        cap reached, EOS emitted, or no room for another token's KV
+        within max_len."""
         eos = (self.cfg.eos_token is not None and r.generated
                and r.generated[-1] == self.cfg.eos_token)
         return bool(len(r.generated) >= r.l_out or eos
@@ -1204,9 +1151,9 @@ class InferenceEngine:
     def _retire(self, finish_at: Optional[dict] = None) -> None:
         """Move completed requests out of the decode batch.
 
-        ``finish_at`` (slot -> time) carries interpolated per-token
-        stamps from a fused decode block; without it a request
-        finishes at the engine clock (the per-step case).
+        ``finish_at`` (slot -> time) carries interpolated stamps from a
+        decode block; without it (a prefill's first token) a request
+        finishes at the engine clock.
         """
         done = []
         for s, r in list(self.active.items()):

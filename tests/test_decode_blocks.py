@@ -1,11 +1,14 @@
 """Fused multi-token decode blocks (device-resident decode).
 
-A decode_block=K engine runs K greedy iterations per jitted dispatch
-(`Model.decode_block` / `decode_block_slots` — a lax.scan with
-on-device EOS / max-len / l_out stopping) and must be *token-identical*
-to per-token stepping on both execution planes, through preemption,
-P/D export of a partially-consumed stream, and EOS stopping mid-block;
-profiler attribution stays per-iteration so the Eq. 2 fit is unchanged.
+Every decode dispatch runs K >= 1 greedy iterations in one jitted
+program (`Model.decode_block` / `decode_block_slots` — a lax.scan with
+on-device EOS / max-len / l_out stopping).  At K=1 and K=8 the engine
+must be *token-identical* to a cache-free greedy reference
+(`Model.forward` over prompt + generated tokens) on both execution
+planes, and K=8 to K=1 through preemption, P/D export of a
+partially-consumed stream, and EOS stopping mid-block; profiler
+attribution stays per-iteration so the Eq. 2 fit is unchanged, and
+the warm-up leaves no decode program to compile in service.
 """
 
 import jax
@@ -16,6 +19,8 @@ from repro.configs import get_smoke_config
 from repro.core.request import Request, RequestState
 from repro.models import build_model
 from repro.serving.engine import EngineConfig, InferenceEngine
+
+from _greedy_ref import greedy_reference
 
 SMOKE = get_smoke_config("qwen7b")
 _MODEL = build_model(SMOKE)
@@ -52,14 +57,16 @@ def _run(eng, prompts, max_new=10):
 
 
 # ---------------------------------------------------------------------------
-# Token identity vs K=1, both planes, multiple chunk/page sizes
+# Token identity vs the cache-free reference, both planes, multiple
+# chunk/page sizes
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("page_size,chunk_size", [(4, 8), (8, 16)])
 def test_paged_blocks_token_identical_to_per_token(page_size, chunk_size):
-    base = _run(_engine(1, page_size, chunk_size), _prompts())
-    blk = _run(_engine(8, page_size, chunk_size), _prompts())
-    assert [r.generated for r in blk] == [r.generated for r in base]
+    want = greedy_reference(_MODEL, _PARAMS, _prompts(), 10)
+    for k in (1, 8):
+        got = _run(_engine(k, page_size, chunk_size), _prompts())
+        assert [r.generated for r in got] == want, k
     # blocks actually ran fused (pure-decode phases exist with 2 slots)
     eng = _engine(8, page_size, chunk_size)
     reqs = _run(eng, _prompts(2, (5, 7)), max_new=12)
@@ -69,9 +76,10 @@ def test_paged_blocks_token_identical_to_per_token(page_size, chunk_size):
 
 
 def test_slot_plane_blocks_token_identical():
-    base = _run(_engine(1, paged=False), _prompts())
-    blk = _run(_engine(8, paged=False), _prompts())
-    assert [r.generated for r in blk] == [r.generated for r in base]
+    want = greedy_reference(_MODEL, _PARAMS, _prompts(), 10)
+    for k in (1, 8):
+        got = _run(_engine(k, paged=False), _prompts())
+        assert [r.generated for r in got] == want, k
 
 
 def test_mamba_blocks_token_identical():
@@ -86,8 +94,9 @@ def test_mamba_blocks_token_identical():
         eng = _engine(k, model=model, params=params, fn_cache=cache)
         return _run(eng, _prompts(2, (5, 9)), max_new=8)
 
-    assert ([r.generated for r in run(8)]
-            == [r.generated for r in run(1)])
+    want = greedy_reference(model, params, _prompts(2, (5, 9)), 8)
+    for k in (1, 8):
+        assert [r.generated for r in run(k)] == want, k
 
 
 # ---------------------------------------------------------------------------
@@ -95,8 +104,7 @@ def test_mamba_blocks_token_identical():
 # ---------------------------------------------------------------------------
 
 def test_eos_stops_mid_block():
-    base = _run(_engine(1, n_slots=1), _prompts(1, (9,)), max_new=12)
-    tokens = base[0].generated
+    (tokens,) = greedy_reference(_MODEL, _PARAMS, _prompts(1, (9,)), 12)
     eos = tokens[5]
     stop = tokens.index(eos)  # first emission of the eos value
     want = tokens[: stop + 1]
@@ -241,3 +249,40 @@ def test_device_table_reuploads_only_on_allocation_change():
     t2 = kv.device_table()
     assert t2 is not t1
     assert (np.asarray(t2) == -1).all()
+
+
+# ---------------------------------------------------------------------------
+# Warm-up: every decode program compiled once, on the served arguments
+# ---------------------------------------------------------------------------
+
+def _decode_programs(eng):
+    """Each decode program the engine holds -> its compiled count."""
+    return {(kind, k): fn._cache_size()
+            for kind, fns in (("block", eng._block_fns),
+                              ("spec", eng._spec_fns))
+            for k, fn in fns.items()}
+
+
+@pytest.mark.parametrize("plane", ["paged", "slots", "spec"])
+def test_warm_decode_blocks_leaves_nothing_to_compile(plane):
+    """After ``warm_decode_blocks`` no decode program compiles again
+    and none is added, while lone requests enter every power-of-two K
+    from fresh host state (l_out = K + 1) and from the previous
+    block's state (l_out 16 walks 8, 4, 2, 1; 17 runs 8 then 8), two
+    requests run K = 1 beside a prefill, and (spec) verify passes run
+    in every proposal-width bucket."""
+    kw = {"paged": {}, "slots": {"paged": False},
+          "spec": {"spec_decode": True, "max_spec_len": 2}}[plane]
+    eng = _engine(8, fn_cache={}, **kw)
+    eng.warm_decode_blocks()
+    warmed = _decode_programs(eng)
+    assert all(n == 1 for n in warmed.values()), warmed
+    prompt = np.array([3, 5, 7, 11] * 3, np.int32)  # the drafter fires
+    for l_out in (17, 16, 9, 5, 3, 2):
+        _run(eng, [prompt], max_new=l_out)
+    _run(eng, _prompts(2, (5, 21)), max_new=6)
+    assert _decode_programs(eng) == warmed
+    if plane == "spec":
+        assert set(eng.spec_depth_hist) == {1, 2}
+    else:
+        assert set(eng.decode_block_hist) == {1, 2, 4, 8}

@@ -35,6 +35,8 @@ from repro.serving.spec_decode import (
 )
 from repro.serving.worker import SimWorker
 
+from _greedy_ref import greedy_reference
+
 SMOKE = get_smoke_config("qwen7b")
 _MODEL = build_model(SMOKE)
 _PARAMS = _MODEL.init(jax.random.key(0))
@@ -254,16 +256,19 @@ def test_truncate_prefix_refcounts():
 
 
 # ---------------------------------------------------------------------------
-# Token identity: --spec-decode vs plain greedy decode
+# Token identity: --spec-decode and plain decode vs the cache-free
+# greedy reference
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("page_size,chunk_size", [(4, 8), (8, 16)])
 def test_spec_token_identical_to_plain(page_size, chunk_size):
+    want = greedy_reference(_MODEL, _PARAMS, _rep_prompts(), 12)
     base = _run(_engine(1, page_size, chunk_size), _rep_prompts(),
                 max_new=12)
+    assert [r.generated for r in base] == want
     eng = _spec_engine(page_size, chunk_size)
     spec = _run(eng, _rep_prompts(), max_new=12)
-    assert [r.generated for r in spec] == [r.generated for r in base]
+    assert [r.generated for r in spec] == want
     # speculation actually fired (repetitive prompts guarantee
     # proposals on the first decode steps) and accounting balances
     assert eng.n_spec_dispatches > 0
@@ -280,8 +285,7 @@ def test_spec_identical_to_fixed_k_blocks():
 
 
 def test_spec_eos_stops_identically():
-    base = _run(_engine(1, n_slots=1), _rep_prompts()[:1], max_new=12)
-    tokens = base[0].generated
+    (tokens,) = greedy_reference(_MODEL, _PARAMS, _rep_prompts()[:1], 12)
     eos = tokens[5]
     want = tokens[: tokens.index(eos) + 1]
     eng = _spec_engine(n_slots=1, eos_token=int(eos))
